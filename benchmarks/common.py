@@ -95,9 +95,10 @@ def method_cfg(method: str, n_clients: int, participation: float,
     raise ValueError(method)
 
 
-def run_method(task: Task, method: str, *, rounds: int, k: int, c: float,
-               local_steps: int, batch: int, n_train: int, n_test: int,
-               noniid: bool, seed: int = 0, eval_every: int = 5):
+def make_sim(task: Task, method: str, *, k: int, c: float, local_steps: int,
+             batch: int, n_train: int, n_test: int, noniid: bool,
+             seed: int = 0):
+    """The FedSim of one (task, method) cell and its test split."""
     (x, y), (xt, yt) = make_data(task, n_train, n_test, seed)
     if noniid:
         cx, cy, nk = partition_dirichlet(x, y, k=k, concentration=0.3,
@@ -115,9 +116,17 @@ def run_method(task: Task, method: str, *, rounds: int, k: int, c: float,
                           trust_mask=tm))
     sim = FedSim(params, loss, apply, opt, cfg, jnp.asarray(cx),
                  jnp.asarray(cy), jnp.asarray(nk))
+    return sim, (jnp.asarray(xt), jnp.asarray(yt))
+
+
+def run_method(task: Task, method: str, *, rounds: int, k: int, c: float,
+               local_steps: int, batch: int, n_train: int, n_test: int,
+               noniid: bool, seed: int = 0, eval_every: int = 5):
+    sim, eval_data = make_sim(task, method, k=k, c=c, local_steps=local_steps,
+                              batch=batch, n_train=n_train, n_test=n_test,
+                              noniid=noniid, seed=seed)
     hist = sim.run(rounds, jax.random.PRNGKey(seed + 99),
-                   eval_data=(jnp.asarray(xt), jnp.asarray(yt)),
-                   eval_every=eval_every)
+                   eval_data=eval_data, eval_every=eval_every)
     return hist, sim.bytes_per_round
 
 

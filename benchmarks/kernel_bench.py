@@ -218,34 +218,32 @@ def _codec_benches(rows):
     key2 = jnp.asarray([1, 2], jnp.uint32)
     t8 = _time(lambda: fp8_quant.quant_pack_tiles(
         x2, a2, key2, interpret=True))
-    t4 = _time(lambda: fp8_quant.quant_pack_sub_tiles(
-        x2, a2, key2, fmt=FP4_E2M1, interpret=True))
+    t4 = _time(lambda: fp8_quant.fold_codes(fp8_quant.quant_pack_tiles(
+        x2, a2, key2, fmt=FP4_E2M1, interpret=True), FP4_E2M1))
     n = R * fp8_quant.WIRE_LANE
     _row(rows, "wire_encode_fp8_tiles_0p5M", t8,
          f"fused quantize+pack, {n} B payload")
     _row(rows, "wire_encode_fp4_packed_0p5M", t4,
-         f"fused quantize+pack at 2 codes/byte, {n // 2} B payload "
+         f"quantize+pack kernel, then 2 codes/byte fold, {n // 2} B payload "
          "(half the FP8 wire)")
     c8 = fp8_quant.quant_pack_tiles(x2, a2, key2, interpret=True)
-    c4 = fp8_quant.quant_pack_sub_tiles(x2, a2, key2, fmt=FP4_E2M1,
-                                        interpret=True)
+    c4 = fp8_quant.fold_codes(fp8_quant.quant_pack_tiles(
+        x2, a2, key2, fmt=FP4_E2M1, interpret=True), FP4_E2M1)
     t8d = _time(lambda: fp8_quant.unpack_tiles(c8, a2, interpret=True))
-    t4d = _time(lambda: fp8_quant.unpack_sub_tiles(c4, a2, fmt=FP4_E2M1,
-                                                   interpret=True))
+    t4d = _time(lambda: fp8_quant.unpack_tiles(
+        fp8_quant.unfold_codes(c4, FP4_E2M1).astype(jnp.uint8), a2,
+        fmt=FP4_E2M1, interpret=True))
     _row(rows, "wire_decode_fp8_tiles_0p5M", t8d, "fused unpack-dequantize")
     _row(rows, "wire_decode_fp4_packed_0p5M", t4d,
-         "fused unfold+dequantize from the half-size payload")
+         "unfold, then unpack-dequantize kernel on the half-size payload")
 
 
 def _rans_benches(rows):
-    """16-lane interleaved rANS coder (ISSUE 10): encode (reverse
-    ``lax.scan``, sender-side only — no kernel form) and decode, fused
-    Pallas kernel (interpret mode) vs the jnp ``lax.scan`` fallback.
-    The two decoders share one per-row step function so their symbols
-    are bit-identical by construction — asserted here on top of the
-    roundtrip, mirroring tests/test_entropy.py. Stream: a LeNet-scale
-    32 KiB byte payload drawn FROM the static fp4 table itself (the
-    matched-prior case the wire sees)."""
+    """16-lane interleaved rANS coder: encode (reverse ``lax.scan``) and
+    decode (forward ``lax.scan``; no kernel form — see ``kernels.rans``),
+    with the roundtrip asserted. Stream: a LeNet-scale 32 KiB byte
+    payload drawn FROM the static fp4 table itself (the matched-prior
+    case the wire sees)."""
     from repro.core.entropy import byte_table
     from repro.core.fp8 import FP4_E2M1
     from repro.kernels import rans as rk
@@ -266,19 +264,11 @@ def _rans_benches(rows):
          f"reverse lax.scan, {rk.LANES} lanes; {coded:.0f}/{n} coded B "
          f"({8 * coded / n:.2f} bits/byte)")
 
-    dec_jnp = jax.jit(lambda b, st, ln: rk.rans_decode_jnp(
+    dec = jax.jit(lambda b, st, ln: rk.rans_decode(
         b, st, ln, n, freq, cum, s2s))
-    dec_pal = jax.jit(lambda b, st, ln: rk.rans_decode_pallas(
-        b, st, ln, n, freq, cum, s2s, interpret=True))
-    assert bool(jnp.all(dec_jnp(buf, state, lens) == syms))
-    assert bool(jnp.all(dec_pal(buf, state, lens) == syms))
-    t_j = _time(dec_jnp, buf, state, lens, n=10)
-    t_p = _time(dec_pal, buf, state, lens, n=10)
-    _row(rows, "rans_decode_jnp_32k", t_j,
-         "lax.scan fallback (bit-identical to the kernel)")
-    _row(rows, "rans_decode_pallas_interp_32k", t_p,
-         "fused fori_loop decode, table + buffer in VMEM "
-         "(structural only on CPU)")
+    assert bool(jnp.all(dec(buf, state, lens) == syms))
+    t_d = _time(dec, buf, state, lens, n=10)
+    _row(rows, "rans_decode_jnp_32k", t_d, "lax.scan decode")
 
 
 def _scaling_benches(rows):
@@ -347,7 +337,7 @@ def _scaling_fed2d_benches(rows):
     and pmaxes only the byproduct amax row — one scalar per segment,
     OFF the critical path; ``frozen`` has no collective at all. jnp
     backend inside shard_map (scheduling is the subject)."""
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from repro.launch.mesh import make_fed_mesh
@@ -391,7 +381,7 @@ def _scaling_fed2d_benches(rows):
         fn = jax.jit(shard_map(
             body, mesh=mesh, in_specs=(P("fsdp", None), P()),
             out_specs=(P("fsdp", None), P()) if body is body_delayed
-            else P("fsdp", None), check_rep=False,
+            else P("fsdp", None), check_vma=False,
         ))
         return _time(fn, x2, key2)
 

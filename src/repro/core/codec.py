@@ -30,8 +30,8 @@ only :class:`DeltaCodec` uses it. Implementations:
   SR, Lemma 3) or 'det' (the biased Table-2 ablation).
 * :class:`Fp32Codec`   — the 'none' leg: 4 bytes/element passthrough.
 * :class:`PackedFpCodec` — sub-byte ExMy formats (Noune et al.): FP4
-  E2M1/E3M0 at 2 codes/byte through the fused pack/unpack kernels
-  (``kernels.fp8_quant.quant_pack_sub_tiles``). Halves the quantized-leg
+  E2M1/E3M0 at 2 codes/byte (``kernels.dispatch.quant_pack_sub_tiles``:
+  the FP8 pack kernel's codes, folded in pairs). Halves the quantized-leg
   payload vs FP8.
 * :class:`DeltaCodec(inner)` — transmits the quantized *residual* against
   ``ref``; with a stochastic inner rounding the leg stays unbiased (SR of
@@ -430,9 +430,9 @@ class PackedFpCodec(Fp8Codec):
     """Sub-byte ExMy wire: ``8 // fmt.bits`` codes per payload byte.
 
     FP4 (E2M1 or E3M0) packs 2 codes/byte — half the quantized-leg payload
-    of FP8 — through the fused pack/unpack kernels
-    (``kernels.fp8_quant.quant_pack_sub_tiles`` / ``unpack_sub_tiles``),
-    which reuse the SAME parametric (exp, mant) grid and per-element
+    of FP8 — through ``kernels.dispatch.quant_pack_sub_tiles`` /
+    ``unpack_sub_tiles``, which fold the codes of the 1-byte wire kernels:
+    the SAME parametric (exp, mant) grid and per-element
     counter RNG as the FP8 wire. A leaf of n elements occupies exactly
     ``ceil(n * bits / 8)`` wire bytes (an odd tail element shares its byte
     with a zero-code pad nibble — deterministic in both rounding modes, so

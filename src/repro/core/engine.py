@@ -66,6 +66,7 @@ from .faults import FaultModel, quorum_count
 from .fp8 import E4M3, E5M2, FP8Format
 from .qat import QATConfig
 from .server_opt import ServerOptConfig, server_optimize, weighted_mean
+from ..kernels import dispatch
 from ..optim.base import Optimizer, apply_updates
 
 Array = jax.Array
@@ -1071,7 +1072,7 @@ class ShardedExecutor:
 
     def __call__(self, local_update, down: PyTree, data: Array,
                  labels: Array, keys: Array):
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec
 
         P = data.shape[0]
@@ -1094,7 +1095,7 @@ class ShardedExecutor:
             shard_fn, mesh=self.mesh,
             in_specs=(PartitionSpec(), sh, sh, sh),
             out_specs=(PartitionSpec(), PartitionSpec()),
-            check_rep=False,
+            check_vma=False,
         )(down, data[pad_idx], labels[pad_idx], keys[pad_idx])
 
 
@@ -1685,7 +1686,7 @@ class RoundEngine:
         gather, the same cohort stack, so those stages are device-count
         invariant by construction.
         """
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec
 
         P = self.cohort
@@ -1723,11 +1724,23 @@ class RoundEngine:
             nk_sel = nk[idx]
 
             # --- stage 2a: downlink (replicated: ONE encode+decode) ------
+            # inside a fully-replicated shard_map, like the aggregation
+            # tail below: GSPMD cannot partition the codec's Mosaic
+            # kernels, so every device runs the same encode+decode locally
+            rep = PartitionSpec()
             if down_scaled_leg:
-                down, st_down = link.down_scaled(server_params, spec,
-                                                 k_down, st_down)
+                down, st_down = shard_map(
+                    lambda p, k, st: link.down_scaled(p, spec, k, st),
+                    mesh=mesh, in_specs=(rep, rep, rep),
+                    out_specs=(rep, rep), check_vma=False,
+                )(server_params, k_down, st_down)
             else:
-                down = link.down(server_params, spec, k_down, r=r)
+                # r is None (an empty pytree) unless the round is scheduled
+                down = shard_map(
+                    lambda p, k, r_op: link.down(p, spec, k, r=r_op),
+                    mesh=mesh, in_specs=(rep, rep, rep), out_specs=rep,
+                    check_vma=False,
+                )(server_params, k_down, r)
 
             # same fan-out as the local round; the pad wraps cohort rows
             # (keys included) so padded clients are exact duplicates whose
@@ -1775,7 +1788,7 @@ class RoundEngine:
                     shard_body_scaled, mesh=mesh,
                     in_specs=(rep, sh, sh, sh, sh, rep),
                     out_specs=(rep, rep, rep),
-                    check_rep=False,
+                    check_vma=False,
                 )(down, data[sel], labels[sel], loc_keys[pad_idx],
                   up_keys[pad_idx], st_up)
             elif ef_up:
@@ -1812,7 +1825,7 @@ class RoundEngine:
                     shard_body_ef, mesh=mesh,
                     in_specs=(rep, sh, sh, sh, sh, sh),
                     out_specs=(rep, rep, sh),
-                    check_rep=False,
+                    check_vma=False,
                 )(down, data[sel], labels[sel], loc_keys[pad_idx],
                   up_keys[pad_idx], e_sel_pad)
                 new_e = new_e_pad[:P]
@@ -1824,7 +1837,7 @@ class RoundEngine:
                     shard_body, mesh=mesh,
                     in_specs=(rep, sh, sh, sh, sh, rep),
                     out_specs=(rep, rep),
-                    check_rep=False,
+                    check_vma=False,
                 )(down, data[sel], labels[sel], loc_keys[pad_idx],
                   up_keys[pad_idx], r)
             else:
@@ -1836,7 +1849,7 @@ class RoundEngine:
                     mesh=mesh,
                     in_specs=(rep, sh, sh, sh, sh),
                     out_specs=(rep, rep),
-                    check_rep=False,
+                    check_vma=False,
                 )(down, data[sel], labels[sel], loc_keys[pad_idx],
                   up_keys[pad_idx])
 
@@ -1903,7 +1916,7 @@ class RoundEngine:
                 tail_fn, mesh=mesh,
                 in_specs=(rep, rep, rep, rep, rep, rep),
                 out_specs=(rep, rep, rep),
-                check_rep=False,
+                check_vma=False,
             )(server_params, msgs, nk_agg, k_srv, state.opt, losses)
 
             if faults is not None:
@@ -1994,7 +2007,7 @@ class RoundEngine:
         replicated instead — still one plane launch per device, but over
         the gathered tree.
         """
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import NamedSharding, PartitionSpec
 
         from ..sharding.policy import fed_param_specs
@@ -2076,19 +2089,19 @@ class RoundEngine:
                     down_body_scaled, mesh=mesh,
                     in_specs=(pspecs, rep, rep),
                     out_specs=(pspecs, rep),
-                    check_rep=False,
+                    check_vma=False,
                 )(server_params, k_down, st_down)
             elif scheduled:
                 down = shard_map(
                     down_body, mesh=mesh,
                     in_specs=(pspecs, rep, rep), out_specs=pspecs,
-                    check_rep=False,
+                    check_vma=False,
                 )(server_params, k_down, r)
             else:
                 down = shard_map(
                     lambda p, kd: down_body(p, kd, None), mesh=mesh,
                     in_specs=(pspecs, rep), out_specs=pspecs,
-                    check_rep=False,
+                    check_vma=False,
                 )(server_params, k_down)
 
             # --- stage 3: GSPMD cohort x FSDP training -------------------
@@ -2105,10 +2118,11 @@ class RoundEngine:
                     x, NamedSharding(mesh, PartitionSpec(caxis))
                 )
 
-            stacked, losses = _client_vmap(
-                local_update, down, cohort_c(data[sel]),
-                cohort_c(labels[sel]), cohort_c(loc_keys[pad_idx]),
-            )
+            with dispatch.gspmd_partitioned():
+                stacked, losses = _client_vmap(
+                    local_update, down, cohort_c(data[sel]),
+                    cohort_c(labels[sel]), cohort_c(loc_keys[pad_idx]),
+                )
             stk_specs = _lead(spec_leaves, caxis, treedef)
             stacked = jax.lax.with_sharding_constraint(
                 stacked, jax.tree_util.tree_unflatten(
@@ -2139,34 +2153,35 @@ class RoundEngine:
                 msgs, up_amax = shard_map(
                     up_body_scaled, mesh=mesh,
                     in_specs=(stk_specs, PartitionSpec(caxis), rep),
-                    out_specs=(out_stk, rep), check_rep=False,
+                    out_specs=(out_stk, rep), check_vma=False,
                 )(stacked, up_keys[pad_idx], st_up)
             elif scheduled:
                 msgs = shard_map(
                     up_body, mesh=mesh,
                     in_specs=(stk_specs, PartitionSpec(caxis), pspecs, rep),
-                    out_specs=out_stk, check_rep=False,
+                    out_specs=out_stk, check_vma=False,
                 )(stacked, up_keys[pad_idx], down, r)
             else:
                 msgs = shard_map(
                     lambda cp, uk, dn: up_body(cp, uk, dn, None), mesh=mesh,
                     in_specs=(stk_specs, PartitionSpec(caxis), pspecs),
-                    out_specs=out_stk, check_rep=False,
+                    out_specs=out_stk, check_vma=False,
                 )(stacked, up_keys[pad_idx], down)
             ls = losses[:P]
 
             # --- fault stage (replicated masks; elementwise over the
             # FSDP-sharded trees, so GSPMD broadcasts them for free). The
             # DRAW is pinned inside a fully-replicated shard_map: left in
-            # the open jit, GSPMD shards the cohort-sized bernoulli masks
-            # and the legacy (non-partitionable) threefry changes its bits
-            # under partitioning — the realization would silently differ
-            # from every other round build for the same key ---------------
+            # the open jit, GSPMD would shard the cohort-sized bernoulli
+            # masks, and with jax_threefry_partitionable off (the default
+            # before JAX 0.5) the bits change under partitioning. Pinned,
+            # the realization equals every other round build's for the
+            # same key under either setting --------------------------------
             if faults is not None:
                 fd = shard_map(
                     lambda k_, i_: faults.draw(k_, i_, lat_table),
                     mesh=mesh, in_specs=(rep, rep), out_specs=rep,
-                    check_rep=False,
+                    check_vma=False,
                 )(key, idx)
                 if faults.flips_values:
                     msgs = faults.corrupt_tree(msgs, fd.corrupted, key)
@@ -2205,7 +2220,7 @@ class RoundEngine:
                     tail_fn, mesh=mesh,
                     in_specs=(pspecs, out_stk, rep, rep, opt_specs, rep),
                     out_specs=(pspecs, opt_specs, rep),
-                    check_rep=False,
+                    check_vma=False,
                 )(server_params, msgs, nk_agg, k_srv, state.opt, ls)
             else:
                 # UQ+ grid searches reduce across whole tensors — gather
@@ -2215,7 +2230,7 @@ class RoundEngine:
                     tail_fn, mesh=mesh,
                     in_specs=(rep, rep, rep, rep, rep, rep),
                     out_specs=(rep, rep, rep),
-                    check_rep=False,
+                    check_vma=False,
                 )(server_params, msgs, nk_agg, k_srv, state.opt, ls)
                 new_params = jax.lax.with_sharding_constraint(
                     new_params, shardings

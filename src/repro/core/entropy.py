@@ -9,7 +9,7 @@ Gaussian value model is computable at TRACE time from the quantization
 grid alone — no per-payload table, nothing about the table crosses the
 wire. :class:`RansCodec` range-codes the inner codec's code stream
 against that static table with the 16-lane interleaved rANS coder in
-``kernels.rans`` (decode dispatched through ``kernels.dispatch``).
+``kernels.rans``.
 
 Table model
 ===========
@@ -59,7 +59,6 @@ import numpy as np
 from . import fp8
 from .codec import DeltaCodec, Fp8Codec, WireCodec
 from .fp8 import FP8Format
-from ..kernels import dispatch
 from ..kernels import rans as rans_kernel
 from ..kernels.fp8_quant import codes_per_byte
 
@@ -244,7 +243,7 @@ class RansCodec(WireCodec):
         buf = payload["codes"].reshape(rans_kernel.LANES, -1)
         state, lens = payload["rans"]
         freq, cum, s2s = self._table()
-        syms = dispatch.rans_decode(buf, state, lens, n, freq, cum, s2s)
+        syms = rans_kernel.rans_decode(buf, state, lens, n, freq, cum, s2s)
         return self.inner.decode(
             {"codes": syms.astype(jnp.uint8), "other": payload["other"]},
             spec, ref=ref,

@@ -69,8 +69,8 @@ E5M2 = FP8Format(exp=5, mant=2)
 # Sub-byte ExMy formats (Noune et al., *8-bit Numerical Formats for DNNs*,
 # sweep the exponent/mantissa split below 8 bits). Every function in this
 # module is parameterized by (exp, mant), so the 4-bit grids come for free;
-# the *wire* packing of 2 codes/byte lives in the kernels
-# (``kernels.fp8_quant.quant_pack_sub_tiles``) behind
+# the *wire* packing of 2 codes/byte lives in
+# ``kernels.dispatch.quant_pack_sub_tiles``, behind
 # ``core.codec.PackedFpCodec``.
 FP4_E2M1 = FP8Format(exp=2, mant=1)
 FP4_E3M0 = FP8Format(exp=3, mant=0)

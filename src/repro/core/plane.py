@@ -347,7 +347,7 @@ def quantize_det_sharded(params: PyTree, shardings: PyTree,
     ``PartitionSpec`` (pass ``mesh=``) matching ``params``; fully
     replicated trees fall back to the plain plane quantize.
     """
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     treedef = jax.tree_util.tree_structure(params)
     sh_leaves = treedef.flatten_up_to(shardings)
@@ -373,7 +373,7 @@ def quantize_det_sharded(params: PyTree, shardings: PyTree,
 
     return shard_map(
         body, mesh=mesh, in_specs=(specs,), out_specs=specs,
-        check_rep=False,
+        check_vma=False,
     )(params)
 
 

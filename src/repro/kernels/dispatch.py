@@ -40,10 +40,16 @@ mask (1 to x) — gone after the first optimizer step.
 Shape contract: the fused quantizers require a single (per-tensor) clipping
 scalar; stacked per-layer clipping values of shape ``(L, 1, ..., 1)``
 dispatch to jnp (inside ``lax.scan`` over layers each slice is a scalar, so
-the scanned models do hit the kernels on TPU).
+the scanned models do hit the kernels on TPU). A region that GSPMD
+partitions over a mesh traces on jnp too (:func:`gspmd_partitioned`). Both
+routes are static, decided at trace time and counted in ``JNP_ROUTES``; no
+dispatcher tries a kernel and falls back when it fails.
 """
 from __future__ import annotations
 
+import collections
+import contextlib
+import contextvars
 import functools
 import os
 
@@ -54,12 +60,19 @@ import numpy as np
 from ..core import fp8
 from ..core.fp8 import E4M3, FP8Format
 from . import fp8_matmul, fp8_quant
-from . import rans as rans_kernel
 
 Array = jax.Array
 
 BACKENDS = ("auto", "pallas", "interpret", "jnp")
 _ENV = "REPRO_KERNEL_BACKEND"
+
+
+# Trace-time counts of ops a kernel backend sent to jnp, by reason:
+# ``stacked_clip:<op>`` — a quantizer whose clip is stacked (L, 1, ..., 1);
+# ``gspmd_region`` — a region traced under :func:`gspmd_partitioned`.
+JNP_ROUTES: collections.Counter = collections.Counter()
+
+_GSPMD = contextvars.ContextVar("repro_gspmd_region", default=False)
 
 
 def backend() -> str:
@@ -70,8 +83,28 @@ def backend() -> str:
             f"{_ENV}={choice!r}; expected one of {BACKENDS}"
         )
     if choice == "auto":
-        return "pallas" if jax.default_backend() == "tpu" else "jnp"
+        choice = "pallas" if jax.default_backend() == "tpu" else "jnp"
     return choice
+
+
+@contextlib.contextmanager
+def gspmd_partitioned():
+    """Trace a region that GSPMD partitions over a device mesh.
+
+    GSPMD cannot partition a Mosaic kernel ("Mosaic kernels cannot be
+    automatically partitioned"), so inside the region the ``pallas``
+    backend's dispatchers (:func:`use_kernels`) trace the jnp ops instead;
+    :func:`backend` still reports ``pallas``, and the interpret and jnp
+    backends lower to plain HLO and are unchanged. The choice is static,
+    made at trace time, and counted in ``JNP_ROUTES["gspmd_region"]``.
+    """
+    if backend() == "pallas":
+        JNP_ROUTES["gspmd_region"] += 1
+    token = _GSPMD.set(True)
+    try:
+        yield
+    finally:
+        _GSPMD.reset(token)
 
 
 def _pallas_opts() -> tuple[bool, bool]:
@@ -82,11 +115,22 @@ def _pallas_opts() -> tuple[bool, bool]:
     invoked directly (e.g. via ``kernels.ops``) on a CPU host.
     """
     be = backend()
+    if be == "pallas" and _GSPMD.get():
+        return False, False
     return be in ("pallas", "interpret"), be != "pallas"
 
 
-def _quant_kernel_ok(x, alpha) -> bool:
-    return jnp.ndim(x) >= 1 and jnp.size(alpha) == 1
+def use_kernels() -> bool:
+    """Whether the dispatchers run the Pallas kernels here (False on the
+    jnp backend and inside a :func:`gspmd_partitioned` region on TPU)."""
+    return _pallas_opts()[0]
+
+
+def _quant_kernel_ok(x, alpha, op: str) -> bool:
+    if jnp.size(alpha) != 1:
+        JNP_ROUTES["stacked_clip:" + op] += 1
+        return False
+    return jnp.ndim(x) >= 1
 
 
 def _matmul_kernel_ok(x, w, beta, alpha) -> bool:
@@ -111,7 +155,7 @@ def _rand_with_bits_jnp(x, alpha, bits, fmt: FP8Format):
     s = jnp.exp2(p - b - fmt.mant)
     y = xc / s
     fl = jnp.floor(y)
-    u = bits.astype(jnp.float32) * (1.0 / 4294967296.0)
+    u = fp8_quant.uniform_from_bits(bits)
     q = fl + (u < (y - fl)).astype(jnp.float32)
     return (s * q).astype(x.dtype)
 
@@ -148,7 +192,7 @@ _quant_det_kernel_ste.defvjp(_quant_det_fwd, _quant_det_bwd)
 def quantize_det(x: Array, alpha: Array, fmt: FP8Format = E4M3) -> Array:
     """Deterministic FP8 fake-quant, dispatched (see module docstring)."""
     use, _ = _pallas_opts()
-    if use and _quant_kernel_ok(x, alpha):
+    if use and _quant_kernel_ok(x, alpha, "quantize_det"):
         return _quant_det_kernel_ste(x, alpha, fmt)
     return fp8.quantize_det(x, alpha, fmt)
 
@@ -192,7 +236,7 @@ def quantize_rand(
     bitwise identical.
     """
     use, _ = _pallas_opts()
-    if use and _quant_kernel_ok(x, alpha):
+    if use and _quant_kernel_ok(x, alpha, "quantize_rand"):
         bits = jax.random.bits(key, shape=jnp.shape(x), dtype=jnp.uint32)
         return _quant_rand_kernel_ste(x, alpha, bits, fmt)
     return fp8.quantize_rand(x, alpha, key, fmt)
@@ -304,26 +348,17 @@ def quant_pack_sub_tiles(
 ) -> Array:
     """Quantize+pack at ``8 // fmt.bits`` codes per byte (sub-byte formats).
 
-    Same counter-RNG contract as :func:`quant_pack_tiles` — the jnp
-    fallback quantizes with the identical per-element bits and folds the
-    codes with the same little-endian sub-field layout, so packed payloads
-    are bit-identical across backends.
+    :func:`quant_pack_tiles` produces the codes (same counter-RNG contract)
+    and ``fold_codes`` assembles the bytes, on every backend — so packed
+    payloads are bit-identical across backends.
     """
-    use, interp = _pallas_opts()
-    if use:
-        return fp8_quant.quant_pack_sub_tiles(
-            x2, a2, key2, fmt=fmt, interpret=interp
-        )
-    return fp8_quant.fold_codes(_quant_codes_jnp(x2, a2, key2, fmt), fmt)
+    return fp8_quant.fold_codes(quant_pack_tiles(x2, a2, key2, fmt), fmt)
 
 
 def unpack_sub_tiles(c2: Array, a2: Array, fmt: FP8Format | None = None) -> Array:
     """Decode sub-byte packed code tiles back to (R, LANE) f32 grid values."""
-    use, interp = _pallas_opts()
-    if use:
-        return fp8_quant.unpack_sub_tiles(c2, a2, fmt=fmt, interpret=interp)
-    code = fp8_quant.unfold_codes(c2, fmt)
-    return fp8.unpack_fp8(code, a2, fmt).astype(jnp.float32)
+    code = fp8_quant.unfold_codes(c2, fmt).astype(jnp.uint8)
+    return unpack_tiles(code, a2, fmt)
 
 
 def _rowmax_jnp(x2):
@@ -358,38 +393,8 @@ def quant_pack_sub_amax_tiles(
     fmt: FP8Format | None = None,
 ) -> tuple[Array, Array]:
     """Sub-byte :func:`quant_pack_sub_tiles` + fused per-row raw amax."""
-    use, interp = _pallas_opts()
-    if use:
-        return fp8_quant.quant_pack_sub_amax_tiles(
-            x2, a2, key2, fmt=fmt, interpret=interp
-        )
-    codes = fp8_quant.fold_codes(_quant_codes_jnp(x2, a2, key2, fmt), fmt)
-    return codes, _rowmax_jnp(x2)
-
-
-# ---------------------------------------------------------------------------
-# Entropy-coded wire (core.entropy.RansCodec): static-table rANS decode
-# ---------------------------------------------------------------------------
-
-
-def rans_decode(buf: Array, state: Array, lens: Array, n: int,
-                freq: Array, cum: Array, slot2sym: Array) -> Array:
-    """Decode an interleaved-rANS byte stream back to (n,) symbols.
-
-    Kernel backends run the fused decoder (table + coded buffer in VMEM,
-    one ``fori_loop``); the jnp fallback is a ``lax.scan`` sharing the
-    same per-row step function, so symbols are bit-identical across
-    backends by construction (asserted in tests/test_entropy.py). The
-    ENCODER has no kernel form — it runs once per uplink payload on the
-    sender and is a plain ``lax.scan`` in ``kernels.rans``.
-    """
-    use, interp = _pallas_opts()
-    if use:
-        return rans_kernel.rans_decode_pallas(
-            buf, state, lens, n, freq, cum, slot2sym, interpret=interp
-        )
-    return rans_kernel.rans_decode_jnp(buf, state, lens, n, freq, cum,
-                                       slot2sym)
+    codes, rowmax = quant_pack_amax_tiles(x2, a2, key2, fmt)
+    return fp8_quant.fold_codes(codes, fmt), rowmax
 
 
 # ---------------------------------------------------------------------------

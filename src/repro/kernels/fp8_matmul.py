@@ -20,6 +20,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from ..core.fp8 import _ALPHA_FLOOR, E4M3, FP8Format
 from .fp8_quant import _mant_const, _ste_terms, pad_to_blocks
@@ -104,7 +105,8 @@ def qat_matmul(
 # over the contraction grid axis into the revisited output tile; the mask /
 # clip-routing epilogue runs once, on the last contraction step. The scalar
 # clip-value cotangent accumulates across the whole grid into a revisited
-# (1, 1) block (sequential grid => race-free; cheap partial reduction).
+# (1, 1) SMEM block (Mosaic stores scalars only to SMEM; sequential grid =>
+# race-free; cheap partial reduction).
 # ---------------------------------------------------------------------------
 
 
@@ -118,7 +120,7 @@ def _qat_matmul_dx_kernel(g_ref, w_ref, x_ref, beta_ref, alpha_ref,
 
     @pl.when((i == 0) & (k == 0) & (j == 0))
     def _init_gb():
-        gb_ref[...] = jnp.zeros_like(gb_ref)
+        gb_ref[0, 0] = jnp.float32(0.0)
 
     wq = _fake_quant(w_ref[...].astype(jnp.float32), alpha_ref[0, 0], fmt)
     g = g_ref[...].astype(jnp.float32)
@@ -147,7 +149,7 @@ def _qat_matmul_dw_kernel(g_ref, x_ref, w_ref, beta_ref, alpha_ref,
 
     @pl.when((k == 0) & (j == 0) & (i == 0))
     def _init_ga():
-        ga_ref[...] = jnp.zeros_like(ga_ref)
+        ga_ref[0, 0] = jnp.float32(0.0)
 
     xq = _fake_quant(x_ref[...].astype(jnp.float32), beta_ref[0, 0], fmt)
     g = g_ref[...].astype(jnp.float32)
@@ -204,7 +206,8 @@ def qat_matmul_dx(
         ],
         out_specs=[
             pl.BlockSpec((bm, bk), lambda i, kk, j: (i, kk)),
-            pl.BlockSpec((1, 1), lambda i, kk, j: (0, 0)),
+            pl.BlockSpec((1, 1), lambda i, kk, j: (0, 0),
+                         memory_space=pltpu.SMEM),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((mp, kp), jnp.float32),
@@ -254,7 +257,8 @@ def qat_matmul_dw(
         ],
         out_specs=[
             pl.BlockSpec((bk, bn), lambda kk, j, i: (kk, j)),
-            pl.BlockSpec((1, 1), lambda kk, j, i: (0, 0)),
+            pl.BlockSpec((1, 1), lambda kk, j, i: (0, 0),
+                         memory_space=pltpu.SMEM),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((kp, np_), jnp.float32),

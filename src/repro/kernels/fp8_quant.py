@@ -28,6 +28,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from ..core.fp8 import _ALPHA_FLOOR, E4M3, FP8Format
 
@@ -36,6 +37,19 @@ DEFAULT_BLOCK = (256, 512)  # 256*512*4B*3 buffers ~ 1.5 MiB of 16 MiB VMEM
 
 def _mant_const(fmt: FP8Format) -> float:
     return float(np.log2(2.0 - 2.0 ** (-fmt.mant)))
+
+
+def uniform_from_bits(bits):
+    """u32 random bits -> f32 uniform in [0, 1): ``f32(bits) / 2**32``.
+
+    Mosaic has no u32 -> f32 cast. Both 16-bit halves fit int32, and
+    ``hi * 65536`` is exact, so the sum is ONE rounding of the exact integer
+    — the same float as the direct cast. Kernels and jnp fallbacks share
+    this helper, so every backend draws the same uniforms.
+    """
+    hi = (bits >> jnp.uint32(16)).astype(jnp.int32).astype(jnp.float32)
+    lo = (bits & jnp.uint32(0xFFFF)).astype(jnp.int32).astype(jnp.float32)
+    return (hi * 65536.0 + lo) * (1.0 / 4294967296.0)
 
 
 def _quant_det_kernel(x_ref, alpha_ref, o_ref, *, fmt: FP8Format):
@@ -59,7 +73,7 @@ def _quant_rand_kernel(x_ref, alpha_ref, u_ref, o_ref, *, fmt: FP8Format):
     s = jnp.exp2(p - b - fmt.mant)
     y = xc / s
     fl = jnp.floor(y)
-    u = u_ref[...].astype(jnp.float32) * (1.0 / 4294967296.0)
+    u = uniform_from_bits(u_ref[...])
     q = fl + (u < (y - fl)).astype(jnp.float32)
     o_ref[...] = (s * q).astype(o_ref.dtype)
 
@@ -138,9 +152,10 @@ def quant_rand(
 
 # ---------------------------------------------------------------------------
 # Backward kernels (the paper's STE, fused): one HBM read of (x, g) and one
-# write of gx per element, with the alpha cotangent reduced in VMEM across
-# the whole grid (revisited (1, 1) accumulator block — grid iteration on TPU
-# is sequential, so the accumulation is race-free; same in interpret mode).
+# write of gx per element, with the alpha cotangent reduced across the whole
+# grid into a revisited (1, 1) SMEM accumulator (Mosaic stores scalars only
+# to SMEM; grid iteration on TPU is sequential, so the accumulation is
+# race-free; same in interpret mode).
 #
 # Gradient derivation (matches jnp autodiff of repro.core.fp8.quantize_det,
 # where round/floor are STE and the exponent term p is stop-gradded):
@@ -150,6 +165,10 @@ def quant_rand(
 #   dout/dalpha = sign(x) * 1{|x| > a}        (clip routing)
 #             + (q - y) * s / alpha           (scale term: ds/da = s/a)
 # ---------------------------------------------------------------------------
+
+
+_SMEM_ACC = pl.BlockSpec((1, 1), lambda i, j: (0, 0),
+                         memory_space=pltpu.SMEM)
 
 
 def _ste_terms(x, alpha, fmt: FP8Format):
@@ -173,7 +192,7 @@ def _quant_bwd_kernel(x_ref, alpha_ref, g_ref, gx_ref, ga_ref, *,
         q = jnp.round(y)
     else:
         fl = jnp.floor(y)
-        u = u_ref[...].astype(jnp.float32) * (1.0 / 4294967296.0)
+        u = uniform_from_bits(u_ref[...])
         q = fl + (u < (y - fl)).astype(jnp.float32)
     gx_ref[...] = (g * inside).astype(gx_ref.dtype)
     partial = jnp.sum(
@@ -183,7 +202,7 @@ def _quant_bwd_kernel(x_ref, alpha_ref, g_ref, gx_ref, ga_ref, *,
 
     @pl.when((i == 0) & (j == 0))
     def _init():
-        ga_ref[...] = jnp.zeros_like(ga_ref)
+        ga_ref[0, 0] = jnp.float32(0.0)
 
     ga_ref[0, 0] += partial
 
@@ -217,7 +236,7 @@ def quant_det_bwd(
         functools.partial(_quant_bwd_kernel, fmt=fmt),
         grid=grid,
         in_specs=[tile, scalar, tile],
-        out_specs=[tile, pl.BlockSpec((1, 1), lambda i, j: (0, 0))],
+        out_specs=[tile, _SMEM_ACC],
         out_shape=[
             jax.ShapeDtypeStruct(x2.shape, x2.dtype),
             jax.ShapeDtypeStruct((1, 1), jnp.float32),
@@ -253,7 +272,7 @@ def quant_rand_bwd(
         functools.partial(_quant_rand_bwd_kernel, fmt=fmt),
         grid=grid,
         in_specs=[tile, scalar, tile, tile],
-        out_specs=[tile, pl.BlockSpec((1, 1), lambda i, j: (0, 0))],
+        out_specs=[tile, _SMEM_ACC],
         out_shape=[
             jax.ShapeDtypeStruct(x2.shape, x2.dtype),
             jax.ShapeDtypeStruct((1, 1), jnp.float32),
@@ -312,15 +331,6 @@ def _quant_pack_det_kernel(x_ref, a_ref, o_ref, *, fmt: FP8Format):
     o_ref[...] = _pack_code(xc, a, b, fmt).astype(jnp.uint8)
 
 
-def _quant_pack_rand_kernel(x_ref, a_ref, u_ref, o_ref, *, fmt: FP8Format):
-    x = x_ref[...].astype(jnp.float32)
-    a = a_ref[...].astype(jnp.float32)
-    b = 2.0 ** fmt.exp - jnp.log2(a) + _mant_const(fmt) - 1.0
-    xc = jnp.clip(x, -a, a)
-    u = u_ref[...].astype(jnp.float32) * (1.0 / 4294967296.0)
-    o_ref[...] = _pack_code(xc, a, b, fmt, u=u).astype(jnp.uint8)
-
-
 # -- in-kernel counter-based RNG for the wire codec --------------------------
 #
 # Stochastic rounding on the wire needs a uniform per element, not a
@@ -366,7 +376,7 @@ def _quant_pack_rand_ctr_kernel(x_ref, a_ref, k_ref, o_ref, *,
     row0 = pl.program_id(0) * block_rows
     bits = _tile_counter_bits(jnp.uint32(row0), x.shape,
                               k_ref[0, 0], k_ref[0, 1])
-    u = bits.astype(jnp.float32) * (1.0 / 4294967296.0)
+    u = uniform_from_bits(bits)
     o_ref[...] = _pack_code(xc, a, b, fmt, u=u).astype(jnp.uint8)
 
 
@@ -396,7 +406,7 @@ def _fake_quant_tiles_kernel(x_ref, a_ref, o_ref, *, fmt: FP8Format,
         row0 = pl.program_id(0) * block_rows
         bits = _tile_counter_bits(jnp.uint32(row0), x.shape,
                                   k_ref[0, 0], k_ref[0, 1])
-        u = bits.astype(jnp.float32) * (1.0 / 4294967296.0)
+        u = uniform_from_bits(bits)
         fl = jnp.floor(y)
         q = fl + (u < (y - fl)).astype(jnp.float32)
     # saturated-exponent clamp mirroring _pack_code: with p at max there is
@@ -433,7 +443,7 @@ def fake_quant_bits_jnp(x2, a2, bits, fmt: FP8Format = E4M3):
     if bits is None:
         q = jnp.round(y)
     else:
-        u = bits.astype(jnp.float32) * (1.0 / 4294967296.0)
+        u = uniform_from_bits(bits)
         fl = jnp.floor(y)
         q = fl + (u < (y - fl)).astype(jnp.float32)
     vmax = float(2 ** (fmt.mant + 1) - 1)
@@ -586,9 +596,7 @@ def quant_det_tiles_bwd(
 
 
 def _decode_codes(code, a, fmt: FP8Format):
-    """Shared [sign|exp|mant] field decode -> f32 grid values (the ONE
-    owner of the dequant arithmetic for both the 1-byte and the sub-byte
-    unpack kernels)."""
+    """Shared [sign|exp|mant] field decode -> f32 grid values."""
     b = 2.0 ** fmt.exp - jnp.log2(a) + _mant_const(fmt) - 1.0
     sign = (code >> (fmt.exp + fmt.mant)) & 0x1
     f = (code >> fmt.mant) & (2 ** fmt.exp - 1)
@@ -658,17 +666,17 @@ def quant_pack_tiles(
 
 
 # ---------------------------------------------------------------------------
-# Sub-byte wire codec kernels: ExMy formats with bits < 8 (FP4 E2M1/E3M0)
-# pack ``8 // bits`` codes into each payload byte. The quantization math is
-# the SAME parametric grid as FP8 (``_pack_code`` takes fmt.exp/fmt.mant);
-# only the byte assembly differs: a (bm, LANE) tile of b-bit codes folds to
-# a (bm, LANE / (8//b)) uint8 tile, element ``k*i + j`` in bit field
-# ``j*b .. (j+1)*b`` (little-endian sub-fields, so consecutive elements
-# share a byte and a leaf's packed bytes slice to exactly
-# ``ceil(n * bits / 8)`` on the wire). Stochastic rounding draws the SAME
-# per-element counter bits as the unpacked kernels — the packing never
-# changes a rounding decision, so ``fake_quant_tiles`` at the sub-byte
-# format is the exact transit twin of pack+unpack.
+# Sub-byte wire codes: ExMy formats with bits < 8 (FP4 E2M1/E3M0) pack
+# ``8 // bits`` codes into each payload byte. The quantization math is the
+# SAME parametric grid as FP8, so the 1-byte kernels above produce the codes
+# and ``fold_codes`` assembles the bytes outside the kernel: a (R, LANE) tile
+# of b-bit codes folds to (R, LANE / (8//b)) uint8, element ``k*i + j`` in
+# bit field ``j*b .. (j+1)*b`` (little-endian sub-fields, so consecutive
+# elements share a byte and a leaf's packed bytes slice to exactly
+# ``ceil(n * bits / 8)`` on the wire). The fold has no kernel form: Mosaic
+# refuses the lane-splitting reshape it needs. Packing never changes a
+# rounding decision, so ``fake_quant_tiles`` at the sub-byte format is the
+# exact transit twin of pack+unpack.
 # ---------------------------------------------------------------------------
 
 
@@ -702,101 +710,6 @@ def unfold_codes(packed, fmt: FP8Format):
     parts = [(p >> (fmt.bits * j)) & mask for j in range(k)]
     code = jnp.stack(parts, axis=-1)
     return code.reshape(p.shape[0], p.shape[1] * k)
-
-
-def _quant_pack_sub_det_kernel(x_ref, a_ref, o_ref, *, fmt: FP8Format):
-    x = x_ref[...].astype(jnp.float32)
-    a = a_ref[...].astype(jnp.float32)
-    b = 2.0 ** fmt.exp - jnp.log2(a) + _mant_const(fmt) - 1.0
-    xc = jnp.clip(x, -a, a)
-    o_ref[...] = fold_codes(_pack_code(xc, a, b, fmt), fmt)
-
-
-def _quant_pack_sub_rand_ctr_kernel(x_ref, a_ref, k_ref, o_ref, *,
-                                    fmt: FP8Format, block_rows: int):
-    x = x_ref[...].astype(jnp.float32)
-    a = a_ref[...].astype(jnp.float32)
-    b = 2.0 ** fmt.exp - jnp.log2(a) + _mant_const(fmt) - 1.0
-    xc = jnp.clip(x, -a, a)
-    row0 = pl.program_id(0) * block_rows
-    bits = _tile_counter_bits(jnp.uint32(row0), x.shape,
-                              k_ref[0, 0], k_ref[0, 1])
-    u = bits.astype(jnp.float32) * (1.0 / 4294967296.0)
-    o_ref[...] = fold_codes(_pack_code(xc, a, b, fmt, u=u), fmt)
-
-
-def _unpack_sub_kernel(c_ref, a_ref, o_ref, *, fmt: FP8Format):
-    code = unfold_codes(c_ref[...], fmt)
-    a = a_ref[...].astype(jnp.float32)
-    # shared field-decode arithmetic with the 1-byte _unpack_kernel
-    o_ref[...] = _decode_codes(code, a, fmt).astype(o_ref.dtype)
-
-
-@functools.partial(jax.jit, static_argnames=("fmt", "block_rows", "interpret"))
-def quant_pack_sub_tiles(
-    x2: jax.Array,                  # (R, LANE) f32 wire tile layout
-    a2: jax.Array,                  # (R, 1) or (R, LANE) f32 clipping values
-    key2: jax.Array | None = None,  # (2,) u32 key -> stochastic; None -> det
-    fmt: FP8Format | None = None,   # a sub-byte format (bits divides 8)
-    block_rows: int = 256,
-    interpret: bool = True,
-) -> jax.Array:
-    """Fused quantize+pack at ``8 // fmt.bits`` codes/byte ->
-    (R, LANE * bits / 8) uint8. Same tile/RNG contract as
-    :func:`quant_pack_tiles`; tile padding packs to 0-codes and is sliced
-    off by the caller (``core.codec.PackedFpCodec``)."""
-    k = codes_per_byte(fmt)
-    rows = x2.shape[0]
-    bm = min(block_rows, rows)
-    grid = (pl.cdiv(rows, bm),)
-    tile = pl.BlockSpec((bm, WIRE_LANE), lambda i: (i, 0))
-    tile_a = pl.BlockSpec((bm, a2.shape[1]), lambda i: (i, 0))
-    tile_o = pl.BlockSpec((bm, WIRE_LANE // k), lambda i: (i, 0))
-    out_shape = jax.ShapeDtypeStruct((rows, WIRE_LANE // k), jnp.uint8)
-    if key2 is None:
-        return pl.pallas_call(
-            functools.partial(_quant_pack_sub_det_kernel, fmt=fmt),
-            grid=grid,
-            in_specs=[tile, tile_a],
-            out_specs=tile_o,
-            out_shape=out_shape,
-            interpret=interpret,
-        )(x2, a2)
-    return pl.pallas_call(
-        functools.partial(_quant_pack_sub_rand_ctr_kernel, fmt=fmt,
-                          block_rows=bm),
-        grid=grid,
-        in_specs=[tile, tile_a, pl.BlockSpec((1, 2), lambda i: (0, 0))],
-        out_specs=tile_o,
-        out_shape=out_shape,
-        interpret=interpret,
-    )(x2, a2, jnp.reshape(key2.astype(jnp.uint32), (1, 2)))
-
-
-@functools.partial(jax.jit, static_argnames=("fmt", "block_rows", "interpret"))
-def unpack_sub_tiles(
-    c2: jax.Array,                  # (R, LANE * bits / 8) packed uint8 codes
-    a2: jax.Array,                  # (R, 1) or (R, LANE) f32 clipping values
-    fmt: FP8Format | None = None,
-    block_rows: int = 256,
-    interpret: bool = True,
-) -> jax.Array:
-    """Inverse of :func:`quant_pack_sub_tiles` -> (R, LANE) f32 grid values."""
-    k = codes_per_byte(fmt)
-    rows = c2.shape[0]
-    bm = min(block_rows, rows)
-    grid = (pl.cdiv(rows, bm),)
-    tile_c = pl.BlockSpec((bm, WIRE_LANE // k), lambda i: (i, 0))
-    tile_a = pl.BlockSpec((bm, a2.shape[1]), lambda i: (i, 0))
-    tile_o = pl.BlockSpec((bm, WIRE_LANE), lambda i: (i, 0))
-    return pl.pallas_call(
-        functools.partial(_unpack_sub_kernel, fmt=fmt),
-        grid=grid,
-        in_specs=[tile_c, tile_a],
-        out_specs=tile_o,
-        out_shape=jax.ShapeDtypeStruct((rows, WIRE_LANE), jnp.float32),
-        interpret=interpret,
-    )(c2, a2)
 
 
 # ---------------------------------------------------------------------------
@@ -834,32 +747,8 @@ def _quant_pack_rand_ctr_amax_kernel(x_ref, a_ref, k_ref, o_ref, mx_ref, *,
     row0 = pl.program_id(0) * block_rows
     bits = _tile_counter_bits(jnp.uint32(row0), x.shape,
                               k_ref[0, 0], k_ref[0, 1])
-    u = bits.astype(jnp.float32) * (1.0 / 4294967296.0)
+    u = uniform_from_bits(bits)
     o_ref[...] = _pack_code(xc, a, b, fmt, u=u).astype(jnp.uint8)
-    mx_ref[...] = jnp.max(jnp.abs(x), axis=1, keepdims=True)
-
-
-def _quant_pack_sub_det_amax_kernel(x_ref, a_ref, o_ref, mx_ref, *,
-                                    fmt: FP8Format):
-    x = x_ref[...].astype(jnp.float32)
-    a = a_ref[...].astype(jnp.float32)
-    b = 2.0 ** fmt.exp - jnp.log2(a) + _mant_const(fmt) - 1.0
-    xc = jnp.clip(x, -a, a)
-    o_ref[...] = fold_codes(_pack_code(xc, a, b, fmt), fmt)
-    mx_ref[...] = jnp.max(jnp.abs(x), axis=1, keepdims=True)
-
-
-def _quant_pack_sub_rand_ctr_amax_kernel(x_ref, a_ref, k_ref, o_ref, mx_ref,
-                                         *, fmt: FP8Format, block_rows: int):
-    x = x_ref[...].astype(jnp.float32)
-    a = a_ref[...].astype(jnp.float32)
-    b = 2.0 ** fmt.exp - jnp.log2(a) + _mant_const(fmt) - 1.0
-    xc = jnp.clip(x, -a, a)
-    row0 = pl.program_id(0) * block_rows
-    bits = _tile_counter_bits(jnp.uint32(row0), x.shape,
-                              k_ref[0, 0], k_ref[0, 1])
-    u = bits.astype(jnp.float32) * (1.0 / 4294967296.0)
-    o_ref[...] = fold_codes(_pack_code(xc, a, b, fmt, u=u), fmt)
     mx_ref[...] = jnp.max(jnp.abs(x), axis=1, keepdims=True)
 
 
@@ -881,7 +770,7 @@ def _fake_quant_amax_tiles_kernel(x_ref, a_ref, o_ref, mx_ref, *,
         row0 = pl.program_id(0) * block_rows
         bits = _tile_counter_bits(jnp.uint32(row0), x.shape,
                                   k_ref[0, 0], k_ref[0, 1])
-        u = bits.astype(jnp.float32) * (1.0 / 4294967296.0)
+        u = uniform_from_bits(bits)
         fl = jnp.floor(y)
         q = fl + (u < (y - fl)).astype(jnp.float32)
     vmax = float(2 ** (fmt.mant + 1) - 1)
@@ -935,49 +824,6 @@ def quant_pack_amax_tiles(
         grid=grid,
         in_specs=[tile, tile_a, pl.BlockSpec((1, 2), lambda i: (0, 0))],
         out_specs=[tile, col],
-        out_shape=out_shape,
-        interpret=interpret,
-    )(x2, a2, jnp.reshape(key2.astype(jnp.uint32), (1, 2)))
-
-
-@functools.partial(jax.jit, static_argnames=("fmt", "block_rows", "interpret"))
-def quant_pack_sub_amax_tiles(
-    x2: jax.Array,                  # (R, LANE) f32 wire tile layout
-    a2: jax.Array,                  # (R, 1) or (R, LANE) f32 clipping values
-    key2: jax.Array | None = None,  # (2,) u32 key -> stochastic; None -> det
-    fmt: FP8Format | None = None,   # a sub-byte format (bits divides 8)
-    block_rows: int = 256,
-    interpret: bool = True,
-) -> tuple[jax.Array, jax.Array]:
-    """:func:`quant_pack_sub_tiles` + fused per-row raw amax ->
-    ``(codes (R, LANE * bits / 8) u8, rowmax (R, 1) f32)``."""
-    k = codes_per_byte(fmt)
-    rows = x2.shape[0]
-    bm = min(block_rows, rows)
-    grid = (pl.cdiv(rows, bm),)
-    tile = pl.BlockSpec((bm, WIRE_LANE), lambda i: (i, 0))
-    tile_a = pl.BlockSpec((bm, a2.shape[1]), lambda i: (i, 0))
-    tile_o = pl.BlockSpec((bm, WIRE_LANE // k), lambda i: (i, 0))
-    col = pl.BlockSpec((bm, 1), lambda i: (i, 0))
-    out_shape = [
-        jax.ShapeDtypeStruct((rows, WIRE_LANE // k), jnp.uint8),
-        jax.ShapeDtypeStruct((rows, 1), jnp.float32),
-    ]
-    if key2 is None:
-        return pl.pallas_call(
-            functools.partial(_quant_pack_sub_det_amax_kernel, fmt=fmt),
-            grid=grid,
-            in_specs=[tile, tile_a],
-            out_specs=[tile_o, col],
-            out_shape=out_shape,
-            interpret=interpret,
-        )(x2, a2)
-    return pl.pallas_call(
-        functools.partial(_quant_pack_sub_rand_ctr_amax_kernel, fmt=fmt,
-                          block_rows=bm),
-        grid=grid,
-        in_specs=[tile, tile_a, pl.BlockSpec((1, 2), lambda i: (0, 0))],
-        out_specs=[tile_o, col],
         out_shape=out_shape,
         interpret=interpret,
     )(x2, a2, jnp.reshape(key2.astype(jnp.uint32), (1, 2)))

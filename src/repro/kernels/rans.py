@@ -26,20 +26,16 @@ first; the decoder scans forward, reading each lane's stream backward —
 exactly the stack discipline rANS requires, verified bit-exact by
 roundtrip in tests/test_entropy.py.
 
-The decoder exists twice with identical math: ``rans_decode_jnp`` (a
-``lax.scan``) and ``rans_decode_pallas`` (one fused kernel: the whole
-coded buffer in VMEM, a ``fori_loop`` over rows). Both call the same
-``_decode_step``, so bit-identity is by construction; the dispatch seam
-(``kernels.dispatch.rans_decode``) picks the backend like every other
-kernel in this package.
+The decoder is one ``lax.scan`` (:func:`rans_decode`) on every backend. It
+has no Pallas form: each step gathers from the 4096-slot table and from
+per-lane byte positions, and Mosaic lowers only 2-D gathers, so a fused
+kernel is refused on TPU. Decode is one sequential pass per downlink
+payload, off the training hot path.
 """
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
 
 Array = jax.Array
 
@@ -127,9 +123,7 @@ def rans_encode(syms: Array, freq: Array, cum: Array):
 
 def _decode_step(x, rpos, buf, freq, cum, slot2sym, cols):
     """One row of the forward decode: pop LANES symbols, renorm by
-    reading each lane's stream backward. Shared verbatim by the jnp scan
-    and the pallas kernel so the two backends are bit-identical by
-    construction."""
+    reading each lane's stream backward."""
     lane = _lane_ids()
     slot = x & (TAB - 1)
     sym = slot2sym[slot]
@@ -144,9 +138,9 @@ def _decode_step(x, rpos, buf, freq, cum, slot2sym, cols):
     return x, rpos, sym
 
 
-def rans_decode_jnp(buf: Array, state: Array, lens: Array, n: int,
-                    freq: Array, cum: Array, slot2sym: Array) -> Array:
-    """Reference decoder: ``lax.scan`` inverse of :func:`rans_encode`.
+def rans_decode(buf: Array, state: Array, lens: Array, n: int,
+                freq: Array, cum: Array, slot2sym: Array) -> Array:
+    """``lax.scan`` inverse of :func:`rans_encode`.
     ``n`` is the static symbol count; returns (n,) int32 symbols."""
     steps = n_steps(n)
     cols = buf.shape[1]
@@ -163,40 +157,4 @@ def rans_decode_jnp(buf: Array, state: Array, lens: Array, n: int,
     x0 = state.astype(jnp.int32)
     rpos0 = lens.astype(jnp.int32) - 1
     _, rows = jax.lax.scan(step, (x0, rpos0), None, length=steps)
-    return rows.reshape(-1)[:n]
-
-
-def _decode_kernel(buf_ref, state_ref, lens_ref, freq_ref, cum_ref,
-                   s2s_ref, o_ref, *, steps: int, cols: int):
-    buf = buf_ref[...]
-    freq = freq_ref[...]
-    cum = cum_ref[...]
-    s2s = s2s_ref[...]
-
-    def body(t, carry):
-        x, rpos = carry
-        x, rpos, sym = _decode_step(x, rpos, buf, freq, cum, s2s, cols)
-        o_ref[pl.ds(t, 1), :] = sym[None, :]
-        return x, rpos
-
-    x0 = state_ref[...].astype(jnp.int32)
-    rpos0 = lens_ref[...].astype(jnp.int32) - 1
-    jax.lax.fori_loop(0, steps, body, (x0, rpos0))
-
-
-def rans_decode_pallas(buf: Array, state: Array, lens: Array, n: int,
-                       freq: Array, cum: Array, slot2sym: Array,
-                       interpret: bool = False) -> Array:
-    """Fused decode: the whole coded buffer and table live in VMEM and
-    one ``fori_loop`` walks the rows — no per-step HBM round trips. Math
-    is :func:`_decode_step`, shared with the jnp scan."""
-    steps = n_steps(n)
-    cols = buf.shape[1]
-    rows = pl.pallas_call(
-        functools.partial(_decode_kernel, steps=steps, cols=cols),
-        out_shape=jax.ShapeDtypeStruct((steps, LANES), jnp.int32),
-        interpret=interpret,
-    )(buf, state.astype(jnp.int32), lens.astype(jnp.int32),
-      freq.astype(jnp.int32), cum.astype(jnp.int32),
-      slot2sym.astype(jnp.int32))
     return rows.reshape(-1)[:n]

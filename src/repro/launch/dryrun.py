@@ -36,6 +36,7 @@ from ..models import registry
 from ..models.common import sharding_rules
 from ..sharding.policy import ShardingPolicy
 from . import hlo_cost
+from .compile_cache import enable_compile_cache
 from .mesh import HBM_BW, ICI_BW, PEAK_FLOPS_BF16, make_production_mesh
 from .steps import make_comm_round, make_decode_step, make_optimizer, \
     make_prefill_step, make_train_step
@@ -258,6 +259,7 @@ def main() -> None:
     ap.add_argument("--all", action="store_true")
     ap.add_argument("--out", default=None)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cells = list(iter_cells()) if args.all else [(args.arch, args.shape)]
     meshes = [False, True] if (args.both_meshes or args.all) else [args.multi_pod]

@@ -28,19 +28,21 @@ import warnings
 
 import jax
 import numpy as np
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh() -> jax.sharding.Mesh:
     """Whatever devices exist locally, as a (data, model=1) mesh — used by
     examples/integration tests so the same trainer code runs on CPU."""
     n = len(jax.devices())
-    return jax.make_mesh((n, 1), ("data", "model"))
+    return jax.make_mesh((n, 1), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 def _virtual_devices_hint(available: int) -> str:

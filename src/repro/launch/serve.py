@@ -17,6 +17,7 @@ import numpy as np
 from .. import configs
 from ..core.qat import DISABLED, QATConfig
 from ..models import registry
+from .compile_cache import enable_compile_cache
 
 
 def main() -> None:
@@ -29,6 +30,7 @@ def main() -> None:
     ap.add_argument("--no-qat", action="store_true")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = configs.get(args.arch)
     if args.reduced:

@@ -328,7 +328,7 @@ def make_comm_round(mesh, param_specs: PyTree, fl_axes: tuple[str, ...],
     which is conservative (never under-scales), and a below-quorum
     discarded round leaves the history untouched.
     """
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     from ..core import scaling as scaling_lib
 
@@ -389,7 +389,7 @@ def make_comm_round(mesh, param_specs: PyTree, fl_axes: tuple[str, ...],
             mesh=mesh,
             in_specs=(param_specs, P()),
             out_specs=param_specs,
-            check_rep=False,
+            check_vma=False,
         )
 
     import numpy as np
@@ -492,7 +492,7 @@ def make_comm_round(mesh, param_specs: PyTree, fl_axes: tuple[str, ...],
             mesh=mesh,
             in_specs=(param_specs, comm_specs, P(), P()),
             out_specs=(param_specs, comm_specs),
-            check_rep=False,
+            check_vma=False,
         )
 
     return shard_map(
@@ -500,7 +500,7 @@ def make_comm_round(mesh, param_specs: PyTree, fl_axes: tuple[str, ...],
         mesh=mesh,
         in_specs=(param_specs, comm_specs, P()),
         out_specs=(param_specs, comm_specs),
-        check_rep=False,
+        check_vma=False,
     )
 
 

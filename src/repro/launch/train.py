@@ -34,6 +34,7 @@ from ..models import registry
 from ..models.common import sharding_rules
 from ..sharding.policy import ShardingPolicy
 from .mesh import make_host_mesh, make_production_mesh
+from .compile_cache import enable_compile_cache
 from .steps import make_comm_round, make_optimizer, make_train_step
 
 
@@ -85,6 +86,7 @@ def main() -> None:
     ap.add_argument("--resume", action="store_true")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = configs.get(args.arch)
     if args.reduced:

@@ -108,7 +108,7 @@ def _fused_dense_ok(p: dict, name: str, x: Array, qcfg: QATConfig,
         return False
     if p[name + "_qa"].size != 1 or p[act_site].size != 1:
         return False
-    return dispatch.backend() in ("pallas", "interpret")
+    return dispatch.use_kernels()
 
 
 def dense(p: dict, name: str, x: Array, qcfg: QATConfig,

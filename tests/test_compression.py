@@ -3,7 +3,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core import compression
@@ -52,7 +52,7 @@ def test_quantized_allreduce_mean_unbiased():
         return compression.quantized_allreduce_mean(p, key, ("pod",))
 
     fn = shard_map(body, mesh=mesh, in_specs=(P(), P()), out_specs=P(),
-                   check_rep=False)
+                   check_vma=False)
     # average many independent quantization draws: should converge to w
     acc = np.zeros(params["w"].shape, np.float64)
     n = 200
@@ -71,6 +71,6 @@ def test_sync_alphas_is_pmax():
         return compression.sync_alphas(p, ("pod",))
 
     out = jax.jit(shard_map(body, mesh=mesh, in_specs=(P(),), out_specs=P(),
-                            check_rep=False))(params)
+                            check_vma=False))(params)
     np.testing.assert_allclose(np.asarray(out["w_qa"]),
                                np.asarray(params["w_qa"]))

@@ -26,7 +26,8 @@ from repro.launch.steps import make_train_step, make_decode_step, make_optimizer
 from repro.launch import hlo_cost
 
 results = {}
-mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 3)
 for arch in ["tinyllama_1_1b", "mamba2_1_3b", "mixtral_8x7b"]:
     cfg = configs.reduced(configs.get(arch))
     policy = ShardingPolicy(mesh)

@@ -516,9 +516,9 @@ def test_fed2d_scheduled_codec_crosses_phase(virtual_devices):
 
 def test_fed2d_fault_round_matches_local(virtual_devices):
     """Active faults on the 2D mesh: the fault draw is pinned replicated
-    (the legacy threefry changes bits when GSPMD partitions it), so every
-    fault metric must be integer-identical to the local round and partial
-    byte accounting must hold."""
+    (with jax_threefry_partitionable off, GSPMD partitioning would change
+    its bits), so every fault metric must be integer-identical to the
+    local round and partial byte accounting must hold."""
     from repro.core.faults import FaultModel
 
     params, loss, apply, opt, data, _ = _mlp_setup(k=8)

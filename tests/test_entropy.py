@@ -13,8 +13,6 @@ twins that always run) for the load-bearing invariants:
 * table integrity — frequencies sum to exactly ``TAB`` with a >=1 floor
   (which caps the max frequency inside the int32-safe region), cum is
   the exclusive prefix sum, ``slot2sym`` inverts it;
-* backend bit-identity — the fused Pallas decoder (interpret mode on
-  CPU) and the jnp ``lax.scan`` fallback produce identical symbols;
 * losslessness at the codec layer — a ``rans:``-wrapped leg decodes to
   the inner codec's values bitwise, and ``fake_quant`` observes exactly
   the inner codec's values.
@@ -102,11 +100,8 @@ def _roundtrip(syms_np, fmt=FP4_E2M1, sigma=0.2):
     assert buf.shape == (rk.LANES, rk.buf_cols(n))
     coded = int(jnp.sum(lens))
     assert coded <= rk.LANES * rk.buf_cols(n)  # static bound dominates
-    out = rk.rans_decode_jnp(buf, state, lens, n, freq, cum, s2s)
+    out = rk.rans_decode(buf, state, lens, n, freq, cum, s2s)
     np.testing.assert_array_equal(np.asarray(out), syms_np)
-    out_pal = rk.rans_decode_pallas(buf, state, lens, n, freq, cum, s2s,
-                                    interpret=True)
-    np.testing.assert_array_equal(np.asarray(out_pal), np.asarray(out))
     return coded
 
 

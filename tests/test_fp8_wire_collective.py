@@ -10,7 +10,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core import compression, wire
@@ -28,7 +28,7 @@ def test_fp8_wire_mean_unbiased_single_device():
 
     fn = jax.jit(shard_map(
         lambda p, k: compression.fp8_wire_allreduce_mean(p, k, ("pod",)),
-        mesh=mesh, in_specs=(P(), P()), out_specs=P(), check_rep=False,
+        mesh=mesh, in_specs=(P(), P()), out_specs=P(), check_vma=False,
     ))
     acc = np.zeros(params["w"].shape, np.float64)
     n = 400
@@ -48,7 +48,7 @@ def test_fp8_wire_collective_moves_uint8():
     params = _params()
     fn = shard_map(
         lambda p, k: compression.fp8_wire_allreduce_mean(p, k, ("pod",)),
-        mesh=mesh, in_specs=(P(), P()), out_specs=P(), check_rep=False,
+        mesh=mesh, in_specs=(P(), P()), out_specs=P(), check_vma=False,
     )
     txt = jax.jit(fn).lower(params, jax.random.PRNGKey(0)).compile().as_text()
     # collective *op* lines only (consumers referencing the gather as an
@@ -70,11 +70,11 @@ def test_fp8_wire_allgather_stacks_silo_trees():
     key = jax.random.PRNGKey(4)
     gathered = jax.jit(shard_map(
         lambda p, k: compression.fp8_wire_allgather(p, k, ("pod",)),
-        mesh=mesh, in_specs=(P(), P()), out_specs=P(), check_rep=False,
+        mesh=mesh, in_specs=(P(), P()), out_specs=P(), check_vma=False,
     ))(params, key)
     reduced = jax.jit(shard_map(
         lambda p, k: compression.fp8_wire_allreduce_mean(p, k, ("pod",)),
-        mesh=mesh, in_specs=(P(), P()), out_specs=P(), check_rep=False,
+        mesh=mesh, in_specs=(P(), P()), out_specs=P(), check_vma=False,
     ))(params, key)
     assert gathered["w"].shape == (1,) + params["w"].shape
     np.testing.assert_allclose(
@@ -170,7 +170,7 @@ def test_fp8_wire_single_collective_for_whole_model():
     params["w2"], params["w2_qa"] = w2, alpha_like(w2)
     fn = shard_map(
         lambda p, k: compression.fp8_wire_allreduce_mean(p, k, ("pod",)),
-        mesh=mesh, in_specs=(P(), P()), out_specs=P(), check_rep=False,
+        mesh=mesh, in_specs=(P(), P()), out_specs=P(), check_vma=False,
     )
     txt = jax.jit(fn).lower(params, jax.random.PRNGKey(0)).compile().as_text()
     u8_gathers = [ln for ln in txt.splitlines()

@@ -116,8 +116,8 @@ VARIANTS = {
     # ``mesh2d`` resolves lazily to make_fed_mesh(C, F) + model_axis so
     # importing this module never touches device state; the test skips
     # when fewer than C*F devices exist (run the multi-device lane:
-    # REPRO_VIRTUAL_DEVICES=8). det wires keep the pins insensitive to
-    # how GSPMD places the legacy (non-partitionable) threefry.
+    # REPRO_VIRTUAL_DEVICES=8). det wires keep the pins independent of
+    # the per-device plane layout, which sets a 2D rand wire's counters.
     "fed2d_2x4_det_mean": dict(comm_mode="det", qat=QATConfig(),
                                mesh2d=(2, 4)),
     "fed2d_2x4_fp4_fedavgm": dict(comm_mode="det", qat=QATConfig(),

@@ -137,3 +137,19 @@ def test_ste_wrapper_gradients():
     ga_oracle = jax.grad(lambda a: jnp.sum(fp8.quantize_det(x, a)))(alpha)
     np.testing.assert_allclose(np.asarray(ga), np.asarray(ga_oracle),
                                rtol=1e-5, atol=1e-5)
+
+
+def test_uniform_from_bits_equals_direct_cast():
+    """The Mosaic-safe u32 -> f32 conversion is the same float as the
+    direct cast (one rounding of the exact integer), edge words included."""
+    edges = np.array([0, 1, 0xFFFF, 0x10000, 2**24 - 1, 2**24, 2**24 + 1,
+                      2**31 - 1, 2**31, 2**32 - 129, 2**32 - 128,
+                      2**32 - 1], np.uint32)
+    rnd = np.asarray(jax.random.bits(jax.random.PRNGKey(11), (1 << 18,),
+                                     jnp.uint32))
+    bits = jnp.asarray(np.concatenate([edges, rnd]))
+    got = np.asarray(fp8_quant.uniform_from_bits(bits))
+    want = np.asarray(bits, np.float64).astype(np.float32) * np.float32(
+        1.0 / 4294967296.0)
+    np.testing.assert_array_equal(got, want)
+    assert got.max() <= 1.0

@@ -181,7 +181,7 @@ def test_delayed_effective_floors():
 # ---------------------------------------------------------------------------
 
 if HAVE_HYPOTHESIS:
-    amaxes = st.floats(min_value=1e-6, max_value=1e4, allow_nan=False,
+    amaxes = st.floats(min_value=np.float32(1e-6), max_value=1e4, allow_nan=False,
                        allow_infinity=False, width=32)
 
     @settings(max_examples=30, deadline=None)
