@@ -379,11 +379,13 @@ def make_local_update(
     Returned fn signature: ``(params0, data, labels, key) -> (params_U, mean_loss)``
     where ``params0`` is the (dequantized) downlink model — the hard master
     reset is implicit in starting from it. Optimizer state is re-initialized
-    every round, as is standard for FedAvg local solvers.
+    every round, as is standard for FedAvg local solvers. The optimizer's
+    init, update and apply run under the ``fl.opt`` scope.
     """
 
     def local_update(params0: PyTree, data: Array, labels: Array, key: Array):
-        opt_state = optimizer.init(params0)
+        with jax.named_scope("fl.opt"):
+            opt_state = optimizer.init(params0)
         n = data.shape[0]
 
         def step(carry, k):
@@ -392,8 +394,10 @@ def make_local_update(
             idx = jax.random.randint(k_batch, (cfg.batch_size,), 0, n)
             xb, yb = data[idx], labels[idx]
             loss, grads = jax.value_and_grad(loss_fn)(params, xb, yb, cfg.qat, k_q)
-            updates, opt_state = optimizer.update(grads, opt_state, params, i)
-            params = apply_updates(params, updates)
+            with jax.named_scope("fl.opt"):
+                updates, opt_state = optimizer.update(grads, opt_state,
+                                                      params, i)
+                params = apply_updates(params, updates)
             return (params, opt_state, i + 1), loss
 
         keys = jax.random.split(key, cfg.local_steps)
@@ -1485,133 +1489,143 @@ class RoundEngine:
             st_down, st_up = state.scales if scaled else ((), ())
             # key-splitting order matches the legacy round exactly, so the
             # fedavg shim (and any same-key replay) is bit-identical
-            k_sel, k_down, k_up, k_loc, k_srv = jax.random.split(key, 5)
+            with jax.named_scope("fl.sample"):
+                k_sel, k_down, k_up, k_loc, k_srv = jax.random.split(key, 5)
 
             spec = wire.make_wire_spec(server_params)
 
             # --- stage 1: cohort selection -------------------------------
-            idx = sampler(nk, k_sel)
-            nk_sel = nk[idx]
+            with jax.named_scope("fl.sample"):
+                idx = sampler(nk, k_sel)
+                nk_sel = nk[idx]
 
             # --- stage 2a: downlink --------------------------------------
-            if down_scaled_leg:
-                down, st_down = link.down_scaled(server_params, spec,
-                                                 k_down, st_down)
-            elif down_dyn:
-                down, down_tb = link.down_traced(server_params, spec,
-                                                 k_down)
-            else:
-                down = link.down(server_params, spec, k_down, r=r)
+            with jax.named_scope("fl.down"):
+                if down_scaled_leg:
+                    down, st_down = link.down_scaled(server_params, spec,
+                                                     k_down, st_down)
+                elif down_dyn:
+                    down, down_tb = link.down_traced(server_params, spec,
+                                                     k_down)
+                else:
+                    down = link.down(server_params, spec, k_down, r=r)
 
             # --- stage 3: local QAT training over the cohort -------------
-            loc_keys = jax.random.split(k_loc, P)
-            client_params, losses = executor(
-                local_update, down, data[idx], labels[idx], loc_keys
-            )
-            # pin the stage boundary: without the barrier XLA fuses the
-            # training tail into the uplink encode and the fused lowering
-            # (and hence the last-ULP accumulation order) would depend on
-            # the CONSUMER — the executor contract is that every schedule
-            # computes the same client params, so materialize them here
-            client_params, losses = jax.lax.optimization_barrier(
-                (client_params, losses)
-            )
-
-            # --- stage 2b: uplink ----------------------------------------
-            # `down` is the round's reference model: every client started
-            # local training from it, so a DeltaCodec uplink quantizes the
-            # residual against a tree both ends hold
-            if up_scaled_leg:
-                msgs, up_amax = link.up_scaled(client_params, spec, k_up,
-                                               P, st_up)
-            elif ef_up:
-                # gather the cohort's residual rows, compensate-encode-
-                # update through the EF codec, scatter back below (after
-                # the fault draw decides who actually transmitted)
-                e_sel = state.clients.resid[idx]
-                msgs, new_e, up_tb = link.up_ef(client_params, spec,
-                                                k_up, P, e_sel)
-            elif up_dyn:
-                msgs, up_tb = link.up_traced(client_params, spec, k_up,
-                                             P, ref=down)
-            else:
-                msgs = link.up(client_params, spec, k_up, P, ref=down, r=r)
-
-            # --- fault stage (statically elided when fault-free, so the
-            # legacy trace — and its bitwise contract — is untouched).
-            # Logically the faults strike between executor and uplink: a
-            # non-transmitting client's payload never reaches the server,
-            # so its row is replaced by the broadcast and its nk zeroed —
-            # survivors are renormalized by sum(nk_eff) inside every
-            # aggregator's weighted mean.
-            if faults is not None:
-                fd = faults.draw(key, idx, lat_table)
-                if faults.flips_values:
-                    msgs = faults.corrupt_tree(msgs, fd.corrupted, key)
-                msgs = _mask_rejected(msgs, fd.accepted, down)
-                n_alive = jnp.sum(fd.accepted.astype(jnp.int32))
-                n_tx = jnp.sum(fd.transmitted.astype(jnp.int32))
-                nk_agg = nk_sel * fd.accepted.astype(nk_sel.dtype)
-                # an all-dead round is always discarded below; the ones
-                # only keep the dead trace's nk-normalization finite so
-                # the discarded result is garbage, never NaN
-                nk_agg = jnp.where(n_alive > 0, nk_agg,
-                                   jnp.ones_like(nk_agg))
-            else:
-                nk_agg = nk_sel
-
-            # --- residual commit (EF): client-side memory. Every client
-            # that TRANSMITTED updates its row — including corrupted ones
-            # (the client cannot see the server's checksum reject);
-            # dropped/timed-out clients never encoded, so they keep their
-            # old rows. A quorum-skipped round still commits (the clients
-            # did compress) — see the core.ef docstring.
-            if ef_up:
-                if faults is not None:
-                    new_e = jnp.where(fd.transmitted[:, None], new_e,
-                                      e_sel)
-                new_clients = state.clients._replace(
-                    resid=state.clients.resid.at[idx].set(new_e)
+            with jax.named_scope("fl.train"):
+                loc_keys = jax.random.split(k_loc, P)
+                client_params, losses = executor(
+                    local_update, down, data[idx], labels[idx], loc_keys
                 )
-            else:
-                new_clients = state.clients
+                # pin the stage boundary: without the barrier XLA fuses
+                # the training tail into the uplink encode and the fused
+                # lowering (and hence the last-ULP accumulation order)
+                # would depend on the CONSUMER — the executor contract is
+                # that every schedule computes the same client params, so
+                # materialize them here
+                client_params, losses = jax.lax.optimization_barrier(
+                    (client_params, losses)
+                )
 
-            # --- delayed-uplink history append ---------------------------
-            # the server's next-round scales come from what it RECEIVED:
-            # rejected clients' amax rows are masked out first (amax >= 0,
-            # so a zeroed row never wins the max); an all-dead round
-            # appends the running history max — finite, and discarded by
-            # the quorum revert below anyway
-            if up_scaled_leg:
-                if faults is not None:
-                    acc = fd.accepted.astype(jnp.float32)[:, None]
-                    row = jnp.max(up_amax * acc, axis=0)
-                    row = jnp.where(n_alive > 0, row,
-                                    jnp.max(st_up, axis=0))
+            # --- stage 2b: uplink, with what strikes it (faults) and the
+            # client- and server-side state it updates (EF residuals,
+            # delayed-scaling history) --------------------------------------
+            with jax.named_scope("fl.up"):
+                # `down` is the round's reference model: every client started
+                # local training from it, so a DeltaCodec uplink quantizes the
+                # residual against a tree both ends hold
+                if up_scaled_leg:
+                    msgs, up_amax = link.up_scaled(client_params, spec, k_up,
+                                                   P, st_up)
+                elif ef_up:
+                    # gather the cohort's residual rows, compensate-encode-
+                    # update through the EF codec, scatter back below (after
+                    # the fault draw decides who actually transmitted)
+                    e_sel = state.clients.resid[idx]
+                    msgs, new_e, up_tb = link.up_ef(client_params, spec,
+                                                    k_up, P, e_sel)
+                elif up_dyn:
+                    msgs, up_tb = link.up_traced(client_params, spec, k_up,
+                                                 P, ref=down)
                 else:
-                    row = jnp.max(up_amax, axis=0)
-                st_up = link.up_p.update(st_up, row)
+                    msgs = link.up(client_params, spec, k_up, P, ref=down, r=r)
+
+                # --- fault stage (statically elided when fault-free, so the
+                # legacy trace — and its bitwise contract — is untouched).
+                # Logically the faults strike between executor and uplink: a
+                # non-transmitting client's payload never reaches the server,
+                # so its row is replaced by the broadcast and its nk zeroed —
+                # survivors are renormalized by sum(nk_eff) inside every
+                # aggregator's weighted mean.
+                if faults is not None:
+                    fd = faults.draw(key, idx, lat_table)
+                    if faults.flips_values:
+                        msgs = faults.corrupt_tree(msgs, fd.corrupted, key)
+                    msgs = _mask_rejected(msgs, fd.accepted, down)
+                    n_alive = jnp.sum(fd.accepted.astype(jnp.int32))
+                    n_tx = jnp.sum(fd.transmitted.astype(jnp.int32))
+                    nk_agg = nk_sel * fd.accepted.astype(nk_sel.dtype)
+                    # an all-dead round is always discarded below; the ones
+                    # only keep the dead trace's nk-normalization finite so
+                    # the discarded result is garbage, never NaN
+                    nk_agg = jnp.where(n_alive > 0, nk_agg,
+                                       jnp.ones_like(nk_agg))
+                else:
+                    nk_agg = nk_sel
+
+                # --- residual commit (EF): client-side memory. Every client
+                # that TRANSMITTED updates its row — including corrupted ones
+                # (the client cannot see the server's checksum reject);
+                # dropped/timed-out clients never encoded, so they keep their
+                # old rows. A quorum-skipped round still commits (the clients
+                # did compress) — see the core.ef docstring.
+                if ef_up:
+                    if faults is not None:
+                        new_e = jnp.where(fd.transmitted[:, None], new_e,
+                                          e_sel)
+                    new_clients = state.clients._replace(
+                        resid=state.clients.resid.at[idx].set(new_e)
+                    )
+                else:
+                    new_clients = state.clients
+
+                # --- delayed-uplink history append ---------------------------
+                # the server's next-round scales come from what it RECEIVED:
+                # rejected clients' amax rows are masked out first (amax >= 0,
+                # so a zeroed row never wins the max); an all-dead round
+                # appends the running history max — finite, and discarded by
+                # the quorum revert below anyway
+                if up_scaled_leg:
+                    if faults is not None:
+                        acc = fd.accepted.astype(jnp.float32)[:, None]
+                        row = jnp.max(up_amax * acc, axis=0)
+                        row = jnp.where(n_alive > 0, row,
+                                        jnp.max(st_up, axis=0))
+                    else:
+                        row = jnp.max(up_amax, axis=0)
+                    st_up = link.up_p.update(st_up, row)
             new_scales = (st_down, st_up) if scaled else ()
 
             # --- stage 4: server aggregation -----------------------------
-            new_params, new_opt = aggregator(
-                server_params, msgs, nk_agg, k_srv, state.opt
-            )
-
-            if faults is not None:
-                # quorum policy: 'skip' needs `quorum` survivors for the
-                # round to count, 'degrade' proceeds with any survivor at
-                # all. A discarded round leaves params AND aggregator
-                # state (momentum/moments) untouched.
-                ok = n_alive >= (quorum if policy == "skip" else 1)
-                keep = lambda new, old: jax.tree.map(
-                    lambda a, b: jnp.where(ok, a, b), new, old
+            with jax.named_scope("fl.aggregate"):
+                new_params, new_opt = aggregator(
+                    server_params, msgs, nk_agg, k_srv, state.opt
                 )
-                new_params = keep(new_params, server_params)
-                new_opt = keep(new_opt, state.opt)
-                if scaled:
-                    # a discarded round must not advance scaling history
-                    new_scales = keep(new_scales, state.scales)
+
+                if faults is not None:
+                    # quorum policy: 'skip' needs `quorum` survivors for
+                    # the round to count, 'degrade' proceeds with any
+                    # survivor at all. A discarded round leaves params AND
+                    # aggregator state (momentum/moments) untouched.
+                    ok = n_alive >= (quorum if policy == "skip" else 1)
+                    keep = lambda new, old: jax.tree.map(
+                        lambda a, b: jnp.where(ok, a, b), new, old
+                    )
+                    new_params = keep(new_params, server_params)
+                    new_opt = keep(new_opt, state.opt)
+                    if scaled:
+                        # a discarded round must not advance scaling
+                        # history
+                        new_scales = keep(new_scales, state.scales)
 
             if faults is not None:
                 # static sub-GiB guard per phase (at the BOUND for dynamic
@@ -1715,55 +1729,72 @@ class RoundEngine:
             server_params = state.params
             r = state.round if scheduled else None
             st_down, st_up = state.scales if scaled else ((), ())
-            k_sel, k_down, k_up, k_loc, k_srv = jax.random.split(key, 5)
+            with jax.named_scope("fl.sample"):
+                k_sel, k_down, k_up, k_loc, k_srv = jax.random.split(key, 5)
 
             spec = wire.make_wire_spec(server_params)
 
             # --- stage 1: cohort selection (replicated) ------------------
-            idx = sampler(nk, k_sel)
-            nk_sel = nk[idx]
+            with jax.named_scope("fl.sample"):
+                idx = sampler(nk, k_sel)
+                nk_sel = nk[idx]
 
             # --- stage 2a: downlink (replicated: ONE encode+decode) ------
             # inside a fully-replicated shard_map, like the aggregation
             # tail below: GSPMD cannot partition the codec's Mosaic
             # kernels, so every device runs the same encode+decode locally
             rep = PartitionSpec()
-            if down_scaled_leg:
-                down, st_down = shard_map(
-                    lambda p, k, st: link.down_scaled(p, spec, k, st),
-                    mesh=mesh, in_specs=(rep, rep, rep),
-                    out_specs=(rep, rep), check_vma=False,
-                )(server_params, k_down, st_down)
-            else:
-                # r is None (an empty pytree) unless the round is scheduled
-                down = shard_map(
-                    lambda p, k, r_op: link.down(p, spec, k, r=r_op),
-                    mesh=mesh, in_specs=(rep, rep, rep), out_specs=rep,
-                    check_vma=False,
-                )(server_params, k_down, r)
+            with jax.named_scope("fl.down"):
+                if down_scaled_leg:
+                    down, st_down = shard_map(
+                        lambda p, k, st: link.down_scaled(p, spec, k, st),
+                        mesh=mesh, in_specs=(rep, rep, rep),
+                        out_specs=(rep, rep), check_vma=False,
+                    )(server_params, k_down, st_down)
+                else:
+                    # r is None (an empty pytree) unless the round is
+                    # scheduled
+                    down = shard_map(
+                        lambda p, k, r_op: link.down(p, spec, k, r=r_op),
+                        mesh=mesh, in_specs=(rep, rep, rep), out_specs=rep,
+                        check_vma=False,
+                    )(server_params, k_down, r)
 
             # same fan-out as the local round; the pad wraps cohort rows
             # (keys included) so padded clients are exact duplicates whose
             # outputs are sliced off inside the shard
-            loc_keys = jax.random.split(k_loc, P)
-            up_keys = jax.random.split(k_up, P)
-            pad_idx = jnp.arange(padded, dtype=jnp.int32) % P
-            sel = idx[pad_idx]
+            with jax.named_scope("fl.train"):
+                loc_keys = jax.random.split(k_loc, P)
+                pad_idx = jnp.arange(padded, dtype=jnp.int32) % P
+                sel = idx[pad_idx]
+                d_sel, l_sel = data[sel], labels[sel]
+                lk_sel = loc_keys[pad_idx]
+            with jax.named_scope("fl.up"):
+                uk_sel = jax.random.split(k_up, P)[pad_idx]
 
             # --- stages 3 + 2b: per-shard training, u8 uplink gather -----
-            def shard_body(dn, d, l, lk, uk, r_op):
-                client_params, losses = ex.run_shard(
-                    local_update, dn, d, l, lk, P
-                )
-                # same stage-boundary pin as the local round: the per-shard
-                # training must not fuse into the encode it feeds
-                client_params, losses = jax.lax.optimization_barrier(
-                    (client_params, losses)
-                )
-                msgs = link.up_gather(client_params, uk, axis, n_keep=P,
-                                      ref=dn, r=r_op)
+            def train_shard(dn, d, l, lk):
+                with jax.named_scope("fl.train"):
+                    client_params, losses = ex.run_shard(
+                        local_update, dn, d, l, lk, P
+                    )
+                    # same stage-boundary pin as the local round: the
+                    # per-shard training must not fuse into the encode it
+                    # feeds
+                    return jax.lax.optimization_barrier(
+                        (client_params, losses)
+                    )
+
+            def gather_losses(losses):
                 g = jax.lax.all_gather(losses, axis)
-                return msgs, g.reshape(-1)[:P]
+                return g.reshape(-1)[:P]
+
+            def shard_body(dn, d, l, lk, uk, r_op):
+                client_params, losses = train_shard(dn, d, l, lk)
+                with jax.named_scope("fl.up"):
+                    msgs = link.up_gather(client_params, uk, axis, n_keep=P,
+                                          ref=dn, r=r_op)
+                    return msgs, gather_losses(losses)
 
             sh = PartitionSpec(axis)
             rep = PartitionSpec()
@@ -1772,25 +1803,19 @@ class RoundEngine:
                 # the shard replicated; the per-client amax byproduct is
                 # gathered alongside the codes and comes back replicated
                 def shard_body_scaled(dn, d, l, lk, uk, st):
-                    client_params, losses = ex.run_shard(
-                        local_update, dn, d, l, lk, P
-                    )
-                    client_params, losses = jax.lax.optimization_barrier(
-                        (client_params, losses)
-                    )
-                    msgs, amax = link.up_gather_scaled(
-                        client_params, uk, axis, n_keep=P, st=st
-                    )
-                    g = jax.lax.all_gather(losses, axis)
-                    return msgs, g.reshape(-1)[:P], amax
+                    client_params, losses = train_shard(dn, d, l, lk)
+                    with jax.named_scope("fl.up"):
+                        msgs, amax = link.up_gather_scaled(
+                            client_params, uk, axis, n_keep=P, st=st
+                        )
+                        return msgs, gather_losses(losses), amax
 
                 msgs, losses, up_amax = shard_map(
                     shard_body_scaled, mesh=mesh,
                     in_specs=(rep, sh, sh, sh, sh, rep),
                     out_specs=(rep, rep, rep),
                     check_vma=False,
-                )(down, data[sel], labels[sel], loc_keys[pad_idx],
-                  up_keys[pad_idx], st_up)
+                )(down, d_sel, l_sel, lk_sel, uk_sel, st_up)
             elif ef_up:
                 # EF uplink fused into the shard: residual rows ride in
                 # cohort-sharded, each shard compensates ITS clients, the
@@ -1800,36 +1825,33 @@ class RoundEngine:
                 # same residual), so slicing [:P] outside recovers the
                 # exact cohort-order rows the local round computes.
                 def shard_body_ef(dn, d, l, lk, uk, e):
-                    client_params, losses = ex.run_shard(
-                        local_update, dn, d, l, lk, P
-                    )
-                    client_params, losses = jax.lax.optimization_barrier(
-                        (client_params, losses)
-                    )
-                    comp = jax.vmap(
-                        lambda p, ei: ef_lib.add_resid(p, ei, spec)
-                    )(client_params, e)
-                    msgs = link.up_gather_ef(comp, uk, axis, n_keep=P)
-                    # this shard's decoded twins: row j here is cohort
-                    # client (start + j) % P of the replicated stack
-                    start = jax.lax.axis_index(axis) * local
-                    take = (start + jnp.arange(local, dtype=jnp.int32)) % P
-                    dec = jax.tree.map(lambda x: x[take], msgs)
-                    flat = jax.vmap(lambda t: ef_lib.flatten_q(t, spec))
-                    new_e = flat(comp) - flat(dec)
-                    g = jax.lax.all_gather(losses, axis)
-                    return msgs, g.reshape(-1)[:P], new_e
+                    client_params, losses = train_shard(dn, d, l, lk)
+                    with jax.named_scope("fl.up"):
+                        comp = jax.vmap(
+                            lambda p, ei: ef_lib.add_resid(p, ei, spec)
+                        )(client_params, e)
+                        msgs = link.up_gather_ef(comp, uk, axis, n_keep=P)
+                        # this shard's decoded twins: row j here is cohort
+                        # client (start + j) % P of the replicated stack
+                        start = jax.lax.axis_index(axis) * local
+                        take = (start
+                                + jnp.arange(local, dtype=jnp.int32)) % P
+                        dec = jax.tree.map(lambda x: x[take], msgs)
+                        flat = jax.vmap(lambda t: ef_lib.flatten_q(t, spec))
+                        new_e = flat(comp) - flat(dec)
+                        return msgs, gather_losses(losses), new_e
 
-                e_sel_pad = state.clients.resid[sel]
+                with jax.named_scope("fl.up"):
+                    e_sel_pad = state.clients.resid[sel]
                 msgs, losses, new_e_pad = shard_map(
                     shard_body_ef, mesh=mesh,
                     in_specs=(rep, sh, sh, sh, sh, sh),
                     out_specs=(rep, rep, sh),
                     check_vma=False,
-                )(down, data[sel], labels[sel], loc_keys[pad_idx],
-                  up_keys[pad_idx], e_sel_pad)
-                new_e = new_e_pad[:P]
-                e_sel = e_sel_pad[:P]
+                )(down, d_sel, l_sel, lk_sel, uk_sel, e_sel_pad)
+                with jax.named_scope("fl.up"):
+                    new_e = new_e_pad[:P]
+                    e_sel = e_sel_pad[:P]
             elif scheduled:
                 # the round-index rides replicated into the shard so the
                 # scheduled uplink resolves its phase inside shard_map
@@ -1838,8 +1860,7 @@ class RoundEngine:
                     in_specs=(rep, sh, sh, sh, sh, rep),
                     out_specs=(rep, rep),
                     check_vma=False,
-                )(down, data[sel], labels[sel], loc_keys[pad_idx],
-                  up_keys[pad_idx], r)
+                )(down, d_sel, l_sel, lk_sel, uk_sel, r)
             else:
                 # no extra operand on unscheduled links: the lowering (and
                 # its pinned bitwise-parity contract) is unchanged
@@ -1850,52 +1871,54 @@ class RoundEngine:
                     in_specs=(rep, sh, sh, sh, sh),
                     out_specs=(rep, rep),
                     check_vma=False,
-                )(down, data[sel], labels[sel], loc_keys[pad_idx],
-                  up_keys[pad_idx])
+                )(down, d_sel, l_sel, lk_sel, uk_sel)
 
-            # --- fault stage (replicated; statically elided when
-            # fault-free). The draw is a pure function of the round key,
-            # so every device computes the same masks; masking is
-            # elementwise (no reduction, nothing to reassociate), so the
-            # sharded==local bitwise contract survives under faults too.
-            if faults is not None:
-                fd = faults.draw(key, idx, lat_table)
-                if faults.flips_values:
-                    msgs = faults.corrupt_tree(msgs, fd.corrupted, key)
-                msgs = _mask_rejected(msgs, fd.accepted, down)
-                n_alive = jnp.sum(fd.accepted.astype(jnp.int32))
-                n_tx = jnp.sum(fd.transmitted.astype(jnp.int32))
-                nk_agg = nk_sel * fd.accepted.astype(nk_sel.dtype)
-                nk_agg = jnp.where(n_alive > 0, nk_agg,
-                                   jnp.ones_like(nk_agg))
-            else:
-                nk_agg = nk_sel
-
-            # --- residual commit (EF, replicated): same semantics as the
-            # local round — transmitters update, dropped clients keep old
-            # rows, quorum-skips still commit (core.ef docstring)
-            if ef_up:
+            with jax.named_scope("fl.up"):
+                # --- fault stage (replicated; statically elided when
+                # fault-free). The draw is a pure function of the round
+                # key, so every device computes the same masks; masking
+                # is elementwise (no reduction, nothing to reassociate),
+                # so the sharded==local bitwise contract survives under
+                # faults too.
                 if faults is not None:
-                    new_e = jnp.where(fd.transmitted[:, None], new_e,
-                                      e_sel)
-                new_clients = state.clients._replace(
-                    resid=state.clients.resid.at[idx].set(new_e)
-                )
-            else:
-                new_clients = state.clients
-
-            # --- delayed-uplink history append (replicated; identical
-            # math to the local round, so the contract holds under
-            # scaling too) ------------------------------------------------
-            if up_scaled_leg:
-                if faults is not None:
-                    acc = fd.accepted.astype(jnp.float32)[:, None]
-                    row = jnp.max(up_amax * acc, axis=0)
-                    row = jnp.where(n_alive > 0, row,
-                                    jnp.max(st_up, axis=0))
+                    fd = faults.draw(key, idx, lat_table)
+                    if faults.flips_values:
+                        msgs = faults.corrupt_tree(msgs, fd.corrupted, key)
+                    msgs = _mask_rejected(msgs, fd.accepted, down)
+                    n_alive = jnp.sum(fd.accepted.astype(jnp.int32))
+                    n_tx = jnp.sum(fd.transmitted.astype(jnp.int32))
+                    nk_agg = nk_sel * fd.accepted.astype(nk_sel.dtype)
+                    nk_agg = jnp.where(n_alive > 0, nk_agg,
+                                       jnp.ones_like(nk_agg))
                 else:
-                    row = jnp.max(up_amax, axis=0)
-                st_up = link.up_p.update(st_up, row)
+                    nk_agg = nk_sel
+
+                # --- residual commit (EF, replicated): same semantics as
+                # the local round — transmitters update, dropped clients
+                # keep old rows, quorum-skips still commit (core.ef
+                # docstring)
+                if ef_up:
+                    if faults is not None:
+                        new_e = jnp.where(fd.transmitted[:, None], new_e,
+                                          e_sel)
+                    new_clients = state.clients._replace(
+                        resid=state.clients.resid.at[idx].set(new_e)
+                    )
+                else:
+                    new_clients = state.clients
+
+                # --- delayed-uplink history append (replicated; identical
+                # math to the local round, so the contract holds under
+                # scaling too) --------------------------------------------
+                if up_scaled_leg:
+                    if faults is not None:
+                        acc = fd.accepted.astype(jnp.float32)[:, None]
+                        row = jnp.max(up_amax * acc, axis=0)
+                        row = jnp.where(n_alive > 0, row,
+                                        jnp.max(st_up, axis=0))
+                    else:
+                        row = jnp.max(up_amax, axis=0)
+                    st_up = link.up_p.update(st_up, row)
             new_scales = (st_down, st_up) if scaled else ()
 
             # --- stage 4: server aggregation (replicated) ----------------
@@ -1912,25 +1935,27 @@ class RoundEngine:
                 new_p, new_o = aggregator(sp, m, w, k, st)
                 return new_p, new_o, jnp.mean(ls)
 
-            new_params, new_opt, mean_loss = shard_map(
-                tail_fn, mesh=mesh,
-                in_specs=(rep, rep, rep, rep, rep, rep),
-                out_specs=(rep, rep, rep),
-                check_vma=False,
-            )(server_params, msgs, nk_agg, k_srv, state.opt, losses)
+            with jax.named_scope("fl.aggregate"):
+                new_params, new_opt, mean_loss = shard_map(
+                    tail_fn, mesh=mesh,
+                    in_specs=(rep, rep, rep, rep, rep, rep),
+                    out_specs=(rep, rep, rep),
+                    check_vma=False,
+                )(server_params, msgs, nk_agg, k_srv, state.opt, losses)
 
-            if faults is not None:
-                # quorum selection outside the tail shard (elementwise,
-                # replicated) — a discarded round leaves params AND
-                # aggregator state untouched, exactly like the local round
-                ok = n_alive >= (quorum if policy == "skip" else 1)
-                keep = lambda new, old: jax.tree.map(
-                    lambda a, b: jnp.where(ok, a, b), new, old
-                )
-                new_params = keep(new_params, server_params)
-                new_opt = keep(new_opt, state.opt)
-                if scaled:
-                    new_scales = keep(new_scales, state.scales)
+                if faults is not None:
+                    # quorum selection outside the tail shard
+                    # (elementwise, replicated) — a discarded round leaves
+                    # params AND aggregator state untouched, exactly like
+                    # the local round
+                    ok = n_alive >= (quorum if policy == "skip" else 1)
+                    keep = lambda new, old: jax.tree.map(
+                        lambda a, b: jnp.where(ok, a, b), new, old
+                    )
+                    new_params = keep(new_params, server_params)
+                    new_opt = keep(new_opt, state.opt)
+                    if scaled:
+                        new_scales = keep(new_scales, state.scales)
 
             if faults is not None:
                 for pr in (_schedule_probe_rounds(link)
@@ -2047,7 +2072,8 @@ class RoundEngine:
             server_params = state.params
             r = state.round if scheduled else None
             st_down, st_up = state.scales if scaled else ((), ())
-            k_sel, k_down, k_up, k_loc, k_srv = jax.random.split(key, 5)
+            with jax.named_scope("fl.sample"):
+                k_sel, k_down, k_up, k_loc, k_srv = jax.random.split(key, 5)
 
             # GLOBAL wire spec: byte accounting only (executor-invariant)
             spec = wire.make_wire_spec(server_params)
@@ -2063,13 +2089,15 @@ class RoundEngine:
             shardings = jax.tree_util.tree_unflatten(
                 treedef, [NamedSharding(mesh, s) for s in spec_leaves]
             )
-            server_params = jax.lax.with_sharding_constraint(
-                server_params, shardings
-            )
+            with jax.named_scope("fl.down"):
+                server_params = jax.lax.with_sharding_constraint(
+                    server_params, shardings
+                )
 
             # --- stage 1: cohort selection (replicated) ------------------
-            idx = sampler(nk, k_sel)
-            nk_sel = nk[idx]
+            with jax.named_scope("fl.sample"):
+                idx = sampler(nk, k_sel)
+                nk_sel = nk[idx]
 
             # --- stage 2a: downlink (model-sharded: ONE encode+decode per
             # device over its local shards; same key on every shard) ------
@@ -2077,62 +2105,64 @@ class RoundEngine:
                 lspec = wire.make_wire_spec(p)
                 return link.down(p, lspec, kd, r=r_op)
 
-            if down_scaled_leg:
-                # per-device scaled encode over the LOCAL plane; the
-                # delayed amax is pmax'd over the model axis inside, so
-                # the state update leaves the shard replicated
-                def down_body_scaled(p, kd, st):
-                    lspec = wire.make_wire_spec(p)
-                    return link.down_scaled(p, lspec, kd, st, axis=maxis)
+            with jax.named_scope("fl.down"):
+                if down_scaled_leg:
+                    # per-device scaled encode over the LOCAL plane; the
+                    # delayed amax is pmax'd over the model axis inside, so
+                    # the state update leaves the shard replicated
+                    def down_body_scaled(p, kd, st):
+                        lspec = wire.make_wire_spec(p)
+                        return link.down_scaled(p, lspec, kd, st, axis=maxis)
 
-                down, st_down = shard_map(
-                    down_body_scaled, mesh=mesh,
-                    in_specs=(pspecs, rep, rep),
-                    out_specs=(pspecs, rep),
-                    check_vma=False,
-                )(server_params, k_down, st_down)
-            elif scheduled:
-                down = shard_map(
-                    down_body, mesh=mesh,
-                    in_specs=(pspecs, rep, rep), out_specs=pspecs,
-                    check_vma=False,
-                )(server_params, k_down, r)
-            else:
-                down = shard_map(
-                    lambda p, kd: down_body(p, kd, None), mesh=mesh,
-                    in_specs=(pspecs, rep), out_specs=pspecs,
-                    check_vma=False,
-                )(server_params, k_down)
+                    down, st_down = shard_map(
+                        down_body_scaled, mesh=mesh,
+                        in_specs=(pspecs, rep, rep),
+                        out_specs=(pspecs, rep),
+                        check_vma=False,
+                    )(server_params, k_down, st_down)
+                elif scheduled:
+                    down = shard_map(
+                        down_body, mesh=mesh,
+                        in_specs=(pspecs, rep, rep), out_specs=pspecs,
+                        check_vma=False,
+                    )(server_params, k_down, r)
+                else:
+                    down = shard_map(
+                        lambda p, kd: down_body(p, kd, None), mesh=mesh,
+                        in_specs=(pspecs, rep), out_specs=pspecs,
+                        check_vma=False,
+                    )(server_params, k_down)
 
             # --- stage 3: GSPMD cohort x FSDP training -------------------
             # clients spread over `caxis` rows (pad wraps cohort rows, so
             # padded clients are duplicates sliced off below); each row's
             # step is partitioned over `maxis` by the sharding constraints
-            loc_keys = jax.random.split(k_loc, P)
-            up_keys = jax.random.split(k_up, P)
-            pad_idx = jnp.arange(padded, dtype=jnp.int32) % P
-            sel = idx[pad_idx]
+            with jax.named_scope("fl.train"):
+                loc_keys = jax.random.split(k_loc, P)
+                pad_idx = jnp.arange(padded, dtype=jnp.int32) % P
+                sel = idx[pad_idx]
 
-            def cohort_c(x):
-                return jax.lax.with_sharding_constraint(
-                    x, NamedSharding(mesh, PartitionSpec(caxis))
-                )
+                def cohort_c(x):
+                    return jax.lax.with_sharding_constraint(
+                        x, NamedSharding(mesh, PartitionSpec(caxis))
+                    )
 
-            with dispatch.gspmd_partitioned():
-                stacked, losses = _client_vmap(
-                    local_update, down, cohort_c(data[sel]),
-                    cohort_c(labels[sel]), cohort_c(loc_keys[pad_idx]),
+                with dispatch.gspmd_partitioned():
+                    stacked, losses = _client_vmap(
+                        local_update, down, cohort_c(data[sel]),
+                        cohort_c(labels[sel]), cohort_c(loc_keys[pad_idx]),
+                    )
+                stk_specs = _lead(spec_leaves, caxis, treedef)
+                stacked = jax.lax.with_sharding_constraint(
+                    stacked, jax.tree_util.tree_unflatten(
+                        treedef,
+                        [NamedSharding(mesh, PartitionSpec(caxis, *s))
+                         for s in spec_leaves],
+                    )
                 )
-            stk_specs = _lead(spec_leaves, caxis, treedef)
-            stacked = jax.lax.with_sharding_constraint(
-                stacked, jax.tree_util.tree_unflatten(
-                    treedef,
-                    [NamedSharding(mesh, PartitionSpec(caxis, *s))
-                     for s in spec_leaves],
-                )
-            )
-            # same stage-boundary pin as every other round build
-            stacked, losses = jax.lax.optimization_barrier((stacked, losses))
+                # same stage-boundary pin as every other round build
+                stacked, losses = jax.lax.optimization_barrier(
+                    (stacked, losses))
 
             # --- stage 2b: uplink (u8 codes move along `caxis` only) -----
             def up_body(cp, uk, dn, r_op):
@@ -2140,71 +2170,75 @@ class RoundEngine:
                                       r=r_op)
 
             out_stk = _lead(spec_leaves, None, treedef)
-            if up_scaled_leg:
-                # scaled uplink: per-device amax over the local shard,
-                # pmax'd over the model axis so the gathered (P, n_q)
-                # row set is globally consistent and fully replicated
-                def up_body_scaled(cp, uk, st):
-                    m, amax = link.up_gather_scaled(
-                        cp, uk, caxis, n_keep=P, st=st
-                    )
-                    return m, jax.lax.pmax(amax, maxis)
+            with jax.named_scope("fl.up"):
+                up_keys = jax.random.split(k_up, P)
+                if up_scaled_leg:
+                    # scaled uplink: per-device amax over the local shard,
+                    # pmax'd over the model axis so the gathered (P, n_q)
+                    # row set is globally consistent and fully replicated
+                    def up_body_scaled(cp, uk, st):
+                        m, amax = link.up_gather_scaled(
+                            cp, uk, caxis, n_keep=P, st=st
+                        )
+                        return m, jax.lax.pmax(amax, maxis)
 
-                msgs, up_amax = shard_map(
-                    up_body_scaled, mesh=mesh,
-                    in_specs=(stk_specs, PartitionSpec(caxis), rep),
-                    out_specs=(out_stk, rep), check_vma=False,
-                )(stacked, up_keys[pad_idx], st_up)
-            elif scheduled:
-                msgs = shard_map(
-                    up_body, mesh=mesh,
-                    in_specs=(stk_specs, PartitionSpec(caxis), pspecs, rep),
-                    out_specs=out_stk, check_vma=False,
-                )(stacked, up_keys[pad_idx], down, r)
-            else:
-                msgs = shard_map(
-                    lambda cp, uk, dn: up_body(cp, uk, dn, None), mesh=mesh,
-                    in_specs=(stk_specs, PartitionSpec(caxis), pspecs),
-                    out_specs=out_stk, check_vma=False,
-                )(stacked, up_keys[pad_idx], down)
-            ls = losses[:P]
-
-            # --- fault stage (replicated masks; elementwise over the
-            # FSDP-sharded trees, so GSPMD broadcasts them for free). The
-            # DRAW is pinned inside a fully-replicated shard_map: left in
-            # the open jit, GSPMD would shard the cohort-sized bernoulli
-            # masks, and with jax_threefry_partitionable off (the default
-            # before JAX 0.5) the bits change under partitioning. Pinned,
-            # the realization equals every other round build's for the
-            # same key under either setting --------------------------------
-            if faults is not None:
-                fd = shard_map(
-                    lambda k_, i_: faults.draw(k_, i_, lat_table),
-                    mesh=mesh, in_specs=(rep, rep), out_specs=rep,
-                    check_vma=False,
-                )(key, idx)
-                if faults.flips_values:
-                    msgs = faults.corrupt_tree(msgs, fd.corrupted, key)
-                msgs = _mask_rejected(msgs, fd.accepted, down)
-                n_alive = jnp.sum(fd.accepted.astype(jnp.int32))
-                n_tx = jnp.sum(fd.transmitted.astype(jnp.int32))
-                nk_agg = nk_sel * fd.accepted.astype(nk_sel.dtype)
-                nk_agg = jnp.where(n_alive > 0, nk_agg,
-                                   jnp.ones_like(nk_agg))
-            else:
-                nk_agg = nk_sel
-
-            # --- delayed-uplink history append (replicated; identical
-            # math to the local round) ------------------------------------
-            if up_scaled_leg:
-                if faults is not None:
-                    acc = fd.accepted.astype(jnp.float32)[:, None]
-                    row = jnp.max(up_amax * acc, axis=0)
-                    row = jnp.where(n_alive > 0, row,
-                                    jnp.max(st_up, axis=0))
+                    msgs, up_amax = shard_map(
+                        up_body_scaled, mesh=mesh,
+                        in_specs=(stk_specs, PartitionSpec(caxis), rep),
+                        out_specs=(out_stk, rep), check_vma=False,
+                    )(stacked, up_keys[pad_idx], st_up)
+                elif scheduled:
+                    msgs = shard_map(
+                        up_body, mesh=mesh,
+                        in_specs=(stk_specs, PartitionSpec(caxis), pspecs,
+                                  rep),
+                        out_specs=out_stk, check_vma=False,
+                    )(stacked, up_keys[pad_idx], down, r)
                 else:
-                    row = jnp.max(up_amax, axis=0)
-                st_up = link.up_p.update(st_up, row)
+                    msgs = shard_map(
+                        lambda cp, uk, dn: up_body(cp, uk, dn, None),
+                        mesh=mesh,
+                        in_specs=(stk_specs, PartitionSpec(caxis), pspecs),
+                        out_specs=out_stk, check_vma=False,
+                    )(stacked, up_keys[pad_idx], down)
+                ls = losses[:P]
+
+                # --- fault stage (replicated masks; elementwise over the
+                # FSDP-sharded trees, so GSPMD broadcasts them for free).
+                # The DRAW is pinned inside a fully-replicated shard_map:
+                # left in the open jit, GSPMD would shard the cohort-sized
+                # bernoulli masks, and with jax_threefry_partitionable off
+                # (the default before JAX 0.5) the bits change under
+                # partitioning. Pinned, the realization equals every other
+                # round build's for the same key under either setting -----
+                if faults is not None:
+                    fd = shard_map(
+                        lambda k_, i_: faults.draw(k_, i_, lat_table),
+                        mesh=mesh, in_specs=(rep, rep), out_specs=rep,
+                        check_vma=False,
+                    )(key, idx)
+                    if faults.flips_values:
+                        msgs = faults.corrupt_tree(msgs, fd.corrupted, key)
+                    msgs = _mask_rejected(msgs, fd.accepted, down)
+                    n_alive = jnp.sum(fd.accepted.astype(jnp.int32))
+                    n_tx = jnp.sum(fd.transmitted.astype(jnp.int32))
+                    nk_agg = nk_sel * fd.accepted.astype(nk_sel.dtype)
+                    nk_agg = jnp.where(n_alive > 0, nk_agg,
+                                       jnp.ones_like(nk_agg))
+                else:
+                    nk_agg = nk_sel
+
+                # --- delayed-uplink history append (replicated; identical
+                # math to the local round) --------------------------------
+                if up_scaled_leg:
+                    if faults is not None:
+                        acc = fd.accepted.astype(jnp.float32)[:, None]
+                        row = jnp.max(up_amax * acc, axis=0)
+                        row = jnp.where(n_alive > 0, row,
+                                        jnp.max(st_up, axis=0))
+                    else:
+                        row = jnp.max(up_amax, axis=0)
+                    st_up = link.up_p.update(st_up, row)
             new_scales = (st_down, st_up) if scaled else ()
 
             # --- stage 4: server aggregation -----------------------------
@@ -2212,39 +2246,40 @@ class RoundEngine:
                 new_p, new_o = aggregator(sp, m, w, k, st)
                 return new_p, new_o, jnp.mean(l_)
 
-            if shard_tail:
-                from ..launch.steps import aggregator_state_specs
+            with jax.named_scope("fl.aggregate"):
+                if shard_tail:
+                    from ..launch.steps import aggregator_state_specs
 
-                opt_specs = aggregator_state_specs(aggregator, pspecs)
-                new_params, new_opt, mean_loss = shard_map(
-                    tail_fn, mesh=mesh,
-                    in_specs=(pspecs, out_stk, rep, rep, opt_specs, rep),
-                    out_specs=(pspecs, opt_specs, rep),
-                    check_vma=False,
-                )(server_params, msgs, nk_agg, k_srv, state.opt, ls)
-            else:
-                # UQ+ grid searches reduce across whole tensors — gather
-                # and run the tail replicated (same lowering as the 1D
-                # sharded round's tail)
-                new_params, new_opt, mean_loss = shard_map(
-                    tail_fn, mesh=mesh,
-                    in_specs=(rep, rep, rep, rep, rep, rep),
-                    out_specs=(rep, rep, rep),
-                    check_vma=False,
-                )(server_params, msgs, nk_agg, k_srv, state.opt, ls)
-                new_params = jax.lax.with_sharding_constraint(
-                    new_params, shardings
-                )
+                    opt_specs = aggregator_state_specs(aggregator, pspecs)
+                    new_params, new_opt, mean_loss = shard_map(
+                        tail_fn, mesh=mesh,
+                        in_specs=(pspecs, out_stk, rep, rep, opt_specs, rep),
+                        out_specs=(pspecs, opt_specs, rep),
+                        check_vma=False,
+                    )(server_params, msgs, nk_agg, k_srv, state.opt, ls)
+                else:
+                    # UQ+ grid searches reduce across whole tensors — gather
+                    # and run the tail replicated (same lowering as the 1D
+                    # sharded round's tail)
+                    new_params, new_opt, mean_loss = shard_map(
+                        tail_fn, mesh=mesh,
+                        in_specs=(rep, rep, rep, rep, rep, rep),
+                        out_specs=(rep, rep, rep),
+                        check_vma=False,
+                    )(server_params, msgs, nk_agg, k_srv, state.opt, ls)
+                    new_params = jax.lax.with_sharding_constraint(
+                        new_params, shardings
+                    )
 
-            if faults is not None:
-                ok = n_alive >= (quorum if policy == "skip" else 1)
-                keep = lambda new, old: jax.tree.map(
-                    lambda a, b: jnp.where(ok, a, b), new, old
-                )
-                new_params = keep(new_params, server_params)
-                new_opt = keep(new_opt, state.opt)
-                if scaled:
-                    new_scales = keep(new_scales, state.scales)
+                if faults is not None:
+                    ok = n_alive >= (quorum if policy == "skip" else 1)
+                    keep = lambda new, old: jax.tree.map(
+                        lambda a, b: jnp.where(ok, a, b), new, old
+                    )
+                    new_params = keep(new_params, server_params)
+                    new_opt = keep(new_opt, state.opt)
+                    if scaled:
+                        new_scales = keep(new_scales, state.scales)
 
             if faults is not None:
                 for pr in (_schedule_probe_rounds(link)

@@ -78,6 +78,7 @@ def qat_matmul(
     out = pl.pallas_call(
         functools.partial(_qat_matmul_kernel, fmt=fmt, n_k=grid[2]),
         grid=grid,
+        name="qat_matmul",
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
             pl.BlockSpec((bk, bn), lambda i, j, kk: (kk, j)),
@@ -197,6 +198,7 @@ def qat_matmul_dx(
     gx, gb = pl.pallas_call(
         functools.partial(_qat_matmul_dx_kernel, fmt=fmt, n_j=grid[2]),
         grid=grid,
+        name="qat_matmul_dx",
         in_specs=[
             pl.BlockSpec((bm, bn), lambda i, kk, j: (i, j)),    # g
             pl.BlockSpec((bk, bn), lambda i, kk, j: (kk, j)),   # w
@@ -248,6 +250,7 @@ def qat_matmul_dw(
     gw, ga = pl.pallas_call(
         functools.partial(_qat_matmul_dw_kernel, fmt=fmt, n_i=grid[2]),
         grid=grid,
+        name="qat_matmul_dw",
         in_specs=[
             pl.BlockSpec((bm, bn), lambda kk, j, i: (i, j)),    # g
             pl.BlockSpec((bm, bk), lambda kk, j, i: (i, kk)),   # x

@@ -117,6 +117,7 @@ def quant_det(
     out = pl.pallas_call(
         functools.partial(_quant_det_kernel, fmt=fmt),
         grid=grid,
+        name="quant_det",
         in_specs=[tile, scalar],
         out_specs=tile,
         out_shape=jax.ShapeDtypeStruct(x2.shape, x2.dtype),
@@ -142,6 +143,7 @@ def quant_rand(
     out = pl.pallas_call(
         functools.partial(_quant_rand_kernel, fmt=fmt),
         grid=grid,
+        name="quant_rand",
         in_specs=[tile, scalar, tile],
         out_specs=tile,
         out_shape=jax.ShapeDtypeStruct(x2.shape, x2.dtype),
@@ -235,6 +237,7 @@ def quant_det_bwd(
     gx, ga = pl.pallas_call(
         functools.partial(_quant_bwd_kernel, fmt=fmt),
         grid=grid,
+        name="quant_det_bwd",
         in_specs=[tile, scalar, tile],
         out_specs=[tile, _SMEM_ACC],
         out_shape=[
@@ -271,6 +274,7 @@ def quant_rand_bwd(
     gx, ga = pl.pallas_call(
         functools.partial(_quant_rand_bwd_kernel, fmt=fmt),
         grid=grid,
+        name="quant_rand_bwd",
         in_specs=[tile, scalar, tile, tile],
         out_specs=[tile, _SMEM_ACC],
         out_shape=[
@@ -481,6 +485,7 @@ def fake_quant_tiles(
             functools.partial(_fake_quant_tiles_kernel, fmt=fmt,
                               block_rows=bm),
             grid=grid,
+            name="fake_quant_tiles",
             in_specs=[tile, tile_a],
             out_specs=tile,
             out_shape=jax.ShapeDtypeStruct(x2.shape, jnp.float32),
@@ -490,6 +495,7 @@ def fake_quant_tiles(
         functools.partial(_fake_quant_tiles_rand_kernel, fmt=fmt,
                           block_rows=bm),
         grid=grid,
+        name="fake_quant_tiles",
         in_specs=[tile, tile_a, pl.BlockSpec((1, 2), lambda i: (0, 0))],
         out_specs=tile,
         out_shape=jax.ShapeDtypeStruct(x2.shape, jnp.float32),
@@ -552,6 +558,7 @@ def quant_det_tiles(
     return pl.pallas_call(
         functools.partial(_quant_det_tiles_kernel, fmt=fmt),
         grid=grid,
+        name="quant_det_tiles",
         in_specs=[tile, tile_a],
         out_specs=tile,
         out_shape=jax.ShapeDtypeStruct(x2.shape, jnp.float32),
@@ -585,6 +592,7 @@ def quant_det_tiles_bwd(
     return pl.pallas_call(
         functools.partial(_quant_det_tiles_bwd_kernel, fmt=fmt),
         grid=grid,
+        name="quant_det_tiles_bwd",
         in_specs=[tile, tile_a, tile],
         out_specs=[tile, col],
         out_shape=[
@@ -649,6 +657,7 @@ def quant_pack_tiles(
         return pl.pallas_call(
             functools.partial(_quant_pack_det_kernel, fmt=fmt),
             grid=grid,
+            name="quant_pack_tiles",
             in_specs=[tile, tile_a],
             out_specs=tile,
             out_shape=jax.ShapeDtypeStruct(x2.shape, jnp.uint8),
@@ -658,6 +667,7 @@ def quant_pack_tiles(
         functools.partial(_quant_pack_rand_ctr_kernel, fmt=fmt,
                           block_rows=bm),
         grid=grid,
+        name="quant_pack_tiles",
         in_specs=[tile, tile_a, pl.BlockSpec((1, 2), lambda i: (0, 0))],
         out_specs=tile,
         out_shape=jax.ShapeDtypeStruct(x2.shape, jnp.uint8),
@@ -813,6 +823,7 @@ def quant_pack_amax_tiles(
         return pl.pallas_call(
             functools.partial(_quant_pack_det_amax_kernel, fmt=fmt),
             grid=grid,
+            name="quant_pack_amax_tiles",
             in_specs=[tile, tile_a],
             out_specs=[tile, col],
             out_shape=out_shape,
@@ -822,6 +833,7 @@ def quant_pack_amax_tiles(
         functools.partial(_quant_pack_rand_ctr_amax_kernel, fmt=fmt,
                           block_rows=bm),
         grid=grid,
+        name="quant_pack_amax_tiles",
         in_specs=[tile, tile_a, pl.BlockSpec((1, 2), lambda i: (0, 0))],
         out_specs=[tile, col],
         out_shape=out_shape,
@@ -854,6 +866,7 @@ def fake_quant_amax_tiles(
             functools.partial(_fake_quant_amax_tiles_kernel, fmt=fmt,
                               block_rows=bm),
             grid=grid,
+            name="fake_quant_amax_tiles",
             in_specs=[tile, tile_a],
             out_specs=[tile, col],
             out_shape=out_shape,
@@ -863,6 +876,7 @@ def fake_quant_amax_tiles(
         functools.partial(_fake_quant_amax_tiles_rand_kernel, fmt=fmt,
                           block_rows=bm),
         grid=grid,
+        name="fake_quant_amax_tiles",
         in_specs=[tile, tile_a, pl.BlockSpec((1, 2), lambda i: (0, 0))],
         out_specs=[tile, col],
         out_shape=out_shape,
@@ -895,6 +909,7 @@ def unpack_tiles(
     return pl.pallas_call(
         functools.partial(_unpack_kernel, fmt=fmt),
         grid=grid,
+        name="unpack_tiles",
         in_specs=[tile, tile_a],
         out_specs=tile,
         out_shape=jax.ShapeDtypeStruct(c2.shape, jnp.float32),

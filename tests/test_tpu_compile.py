@@ -5,16 +5,20 @@ Each kernel is AOT-compiled at real widths for one chip of a described
 and must lower to a ``tpu_custom_call``. This catches what interpret mode
 cannot: casts, scalar stores and reshapes Mosaic refuses. Widths: a
 4096 x 1024 wire/plane tile layout, and TinyLlama's MLP matmul
-(2048 x 2048 @ 2048 x 5632).
+(2048 x 2048 @ 2048 x 5632). A small federated round compiles the same
+way: its kernels keep the names the benchmark's readers match
+(``bench/kernels.py``), and each runs under a stage scope of the round.
 
 The topology is described inside a module fixture, never at import: only
 one process at a time may hold the TPU library, and every test worker
 imports this file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -23,6 +27,12 @@ from repro.kernels import fp8_matmul, fp8_quant
 
 R, LANE = 4096, fp8_quant.WIRE_LANE
 M, K, N = 2048, 2048, 5632
+# The kernel families the benchmark's readers find by substring:
+# ``wire.kernel_ms`` (encode, decode) and the two ``*_roofline`` metrics.
+FAMILIES = ("qat_matmul", "qat_matmul_dx", "qat_matmul_dw",
+            "quant_pack_tiles", "unpack_tiles")
+KERNEL_CALL = re.compile(r"^\s*(?:ROOT\s+)?%([\w.\-]+) = .*"
+                         r'custom_call_target="tpu_custom_call"')
 
 
 @pytest.fixture(scope="module")
@@ -143,3 +153,81 @@ def test_kernel_compiles_for_v5e(case, one_chip, no_persistent_cache):
     fn, args = _cases(S)[case]
     text = jax.jit(fn).lower(*args).compile().as_text()
     assert 'custom_call_target="tpu_custom_call"' in text, case
+    # a kernel is in a family of the benchmark's readers only if it is
+    # that family's kernel
+    names = {_family(n) for n, _ in _kernel_calls(text)}
+    want = {f for f in FAMILIES if case.startswith(f)}
+    assert names == {max(want, key=len) if want else None}, (case, names)
+
+
+def _kernel_calls(text):
+    """(instruction name, op_name) of each Pallas kernel of an HLO text."""
+    for line in text.splitlines():
+        m = KERNEL_CALL.match(line)
+        if m:
+            on = re.search(r'op_name="([^"]*)"', line)
+            yield m.group(1), on.group(1) if on else ""
+
+
+def _family(instr):
+    """The longest family of ``FAMILIES`` in a kernel's instruction name,
+    or None."""
+    found = [f for f in FAMILIES if f in instr]
+    return max(found, key=len) if found else None
+
+
+@pytest.fixture(scope="module")
+def round_kernels(one_chip, no_persistent_cache):
+    """The Pallas kernels of a one-layer LM's federated round compiled
+    for one v5e chip: AdamW clients, E4M3 QAT, stochastic E4M3 wire."""
+    from repro import configs, optim
+    from repro.core.engine import FedConfig, RoundEngine
+    from repro.core.qat import QATConfig
+    from repro.models.registry import get_model
+
+    cfg = configs.get("granite_3_8b").replace(
+        n_layers=1, vocab=256, d_model=128, n_heads=4, n_kv_heads=2,
+        head_dim=32, d_ff=256, ce_chunks=2)
+    model = get_model(cfg)
+
+    def loss(params, xb, yb, qat_cfg, key):
+        return model.train_loss(params, {"tokens": xb, "labels": yb},
+                                qat_cfg)
+
+    fed = FedConfig(n_clients=4, participation=0.5, local_steps=1,
+                    batch_size=2, comm_mode="rand", qat=QATConfig())
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("REPRO_KERNEL_BACKEND", "pallas")
+        eng = RoundEngine(loss, optim.adamw(1e-3, weight_decay=0.01), fed)
+        state = eng.init(model.init(jax.random.PRNGKey(0)))
+        tokens = np.zeros((4, 2, 64), np.int32)
+        args = (state, tokens, tokens, np.ones((4,), np.float32),
+                np.zeros((2,), np.uint32))
+        shapes = jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            np.shape(x), jnp.result_type(x), sharding=one_chip), args)
+        text = jax.jit(eng.round_fn).lower(*shapes).compile().as_text()
+    return list(_kernel_calls(text))
+
+
+def test_round_kernels_keep_their_names(round_kernels):
+    names = {re.sub(r"(\.\d+)+$", "", n) for n, _ in round_kernels}
+    assert names == {"qat_matmul", "qat_matmul_dx", "qat_matmul_dw",
+                     "quant_pack_tiles", "unpack_tiles"}
+    for name in names:
+        assert {f for f in FAMILIES if f in name} == (
+            {"qat_matmul", name} if name.startswith("qat_matmul")
+            else {name}), name
+
+
+def test_round_kernels_run_under_a_stage(round_kernels):
+    stage = {n: next((p for p in on.split("/") if p.startswith("fl.")),
+                     None) for n, on in round_kernels}
+    assert None not in stage.values(), stage
+    by_kernel = {}
+    for n, st in stage.items():
+        by_kernel.setdefault(re.sub(r"(\.\d+)+$", "", n), set()).add(st)
+    assert by_kernel == {"qat_matmul": {"fl.train"},
+                         "qat_matmul_dx": {"fl.train"},
+                         "qat_matmul_dw": {"fl.train"},
+                         "quant_pack_tiles": {"fl.down", "fl.up"},
+                         "unpack_tiles": {"fl.down", "fl.up"}}
