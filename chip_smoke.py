@@ -33,6 +33,7 @@ them at tiny size with ``REPRO_KERNEL_BACKEND=interpret``.
 from __future__ import annotations
 
 import argparse
+import collections
 import contextlib
 import json
 import os
@@ -365,7 +366,10 @@ def kernel_vjp_check(*, rows: int, d_in: int, d_out: int, seed: int = 0,
                      check=check) -> dict:
     """Relative L2 of each cotangent of the fused QAT kernels, Pallas vs
     jnp autodiff (see ``KERNEL_VJP_RTOL``, ``CLIP_VJP_RTOL``), at x (rows,
-    d_in) @ w (d_in, d_out). Fails ``check`` on any cotangent beyond its
+    d_in) @ w (d_in, d_out); the QAT matmul's also with bf16 x and g (keys
+    ``bf16_matmul_*``), the LMs' path, on which the MXU takes bf16
+    operands. Prints the kernels traced by MXU operand dtype
+    (``dispatch.QAT_MXU``). Fails ``check`` on any cotangent beyond its
     tolerance."""
     import jax.numpy as jnp
 
@@ -395,27 +399,39 @@ def kernel_vjp_check(*, rows: int, d_in: int, d_out: int, seed: int = 0,
         q = fl + (uniform_from_bits(bits) < y - fl).astype(jnp.float32)
         return s * (y + jax.lax.stop_gradient(q - y))
 
-    def vjps(x, w, g, gw, beta, alpha, bits, kernels):
+    def vjps(x, w, g, x16, g16, gw, beta, alpha, bits, kernels):
         def rand(v, a):
             if kernels:     # the dispatcher draws ``bits`` from ``kr``
                 return dispatch.quantize_rand(v, a, kr)
             return rand_ste(v, a, bits)
 
         mm = jax.vjp(dispatch.qat_matmul, x, w, beta, alpha)[1](g)
+        mm16 = jax.vjp(dispatch.qat_matmul, x16, w, beta, alpha)[1](g16)
         det = jax.vjp(dispatch.quantize_det, w, alpha)[1](gw)
         rnd = jax.vjp(rand, w, alpha)[1](gw)
-        return dict(zip(("matmul_dx", "matmul_dw", "matmul_dbeta",
-                         "matmul_dalpha", "det_dx", "det_dalpha",
-                         "rand_dx", "rand_dalpha"), (*mm, *det, *rnd)))
+        mm_names = ("matmul_dx", "matmul_dw", "matmul_dbeta", "matmul_dalpha")
+        return dict(zip(mm_names + tuple("bf16_" + n for n in mm_names)
+                        + ("det_dx", "det_dalpha", "rand_dx", "rand_dalpha"),
+                        (*mm, *mm16, *det, *rnd)))
 
-    args = (x, w, g, gw, beta, alpha, bits)
-    got = jax.jit(vjps, static_argnums=7)(*args, True)
+    # the bf16 operands enter as arrays rounded before either side's jit:
+    # cast inside it, XLA on a TPU computed the jnp reference from the
+    # unrounded activations
+    args = (x, w, g, x.astype(jnp.bfloat16), g.astype(jnp.bfloat16), gw,
+            beta, alpha, bits)
+    before = collections.Counter(dispatch.QAT_MXU)
+    got = jax.jit(vjps, static_argnums=9)(*args, True)
+    mxu = dict(dispatch.QAT_MXU - before)
     with kernel_backend("jnp"), jax.default_matmul_precision("highest"):
-        ref = jax.jit(vjps, static_argnums=7)(*args, False)
-    rel = {n: float(jnp.linalg.norm(got[n] - r))
-           / max(float(jnp.linalg.norm(r)), 1e-30) for n, r in ref.items()}
+        ref = jax.jit(vjps, static_argnums=9)(*args, False)
+    f32 = {n: (got[n].astype(jnp.float32), r.astype(jnp.float32))
+           for n, r in ref.items()}
+    rel = {n: float(jnp.linalg.norm(a - r)) / max(float(jnp.linalg.norm(r)),
+                                                  1e-30)
+           for n, (a, r) in f32.items()}
     print(f"[engine_lm] kernel VJPs at ({rows}, {d_in}) @ ({d_in}, "
-          f"{d_out}) vs jnp autodiff (relative L2): {rel}")
+          f"{d_out}) vs jnp autodiff (relative L2): {rel}; QAT matmul "
+          f"kernels traced by MXU operand dtype: {mxu}")
     for n, v in rel.items():
         tol = CLIP_VJP_RTOL if n.endswith(("alpha", "beta")) else \
             KERNEL_VJP_RTOL

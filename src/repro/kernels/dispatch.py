@@ -72,6 +72,11 @@ _ENV = "REPRO_KERNEL_BACKEND"
 # ``gspmd_region`` — a region traced under :func:`gspmd_partitioned`.
 JNP_ROUTES: collections.Counter = collections.Counter()
 
+# Trace-time counts of the fused QAT-matmul kernels traced, by kernel and
+# the dtype of their MXU operands: ``qat_matmul:bfloat16``,
+# ``qat_matmul_dx:float32`` (see ``fp8_matmul.mxu_dtype``).
+QAT_MXU: collections.Counter = collections.Counter()
+
 _GSPMD = contextvars.ContextVar("repro_gspmd_region", default=False)
 
 
@@ -250,6 +255,7 @@ def quantize_rand(
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
 def _qat_matmul_kernel_ste(x, w, beta, alpha, fmt):
     _, interp = _pallas_opts()
+    QAT_MXU["qat_matmul:" + fp8_matmul.CODE_DTYPE.name] += 1
     return fp8_matmul.qat_matmul(x, w, beta, alpha, fmt=fmt, interpret=interp)
 
 
@@ -260,6 +266,9 @@ def _qat_matmul_fwd(x, w, beta, alpha, fmt):
 def _qat_matmul_bwd(fmt, res, g):
     x, w, beta, alpha = res
     _, interp = _pallas_opts()
+    mxu = fp8_matmul.mxu_dtype(g.dtype).name
+    QAT_MXU["qat_matmul_dx:" + mxu] += 1
+    QAT_MXU["qat_matmul_dw:" + mxu] += 1
     gx, gb = fp8_matmul.qat_matmul_dx(
         g, x, w, beta, alpha, fmt=fmt, interpret=interp
     )
@@ -277,16 +286,22 @@ def qat_matmul(
 ) -> Array:
     """``Q_det(x; beta) @ Q_det(w; alpha)`` with f32 accumulation, dispatched.
 
-    On the Pallas path both operand tiles quantize in VMEM right before the
-    MXU (forward) and the backward runs the fused dx/dw kernels; on the jnp
-    path this is the plain composition with native autodiff.
+    The result has ``x``'s dtype. On the Pallas path both operand tiles
+    quantize in VMEM right before the MXU, which multiplies their
+    integer-valued FP8 codes in one bf16 pass and scales the f32
+    accumulation by the two clips' code scales (``kernels/fp8_matmul.py``);
+    the backward runs the fused dx/dw kernels, which feed the MXU the
+    cotangent in its own dtype beside the codes: bf16 for a bf16 ``x``
+    (whose cotangent is bf16), f32 for an f32 one. Each kernel traced is
+    counted in ``QAT_MXU``. On the jnp path this is the plain composition
+    in f32 with native autodiff.
     """
     use, _ = _pallas_opts()
     if use and _matmul_kernel_ok(x, w, beta, alpha):
         return _qat_matmul_kernel_ste(x, w, beta, alpha, fmt)
     out = jnp.dot(
-        fp8.quantize_det(x, beta, fmt).astype(jnp.float32),
-        fp8.quantize_det(w, alpha, fmt).astype(jnp.float32),
+        fp8.quantize_det(x.astype(jnp.float32), beta, fmt),
+        fp8.quantize_det(w.astype(jnp.float32), alpha, fmt),
         preferred_element_type=jnp.float32,
     )
     return out.astype(x.dtype)

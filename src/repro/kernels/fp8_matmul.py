@@ -6,11 +6,29 @@ before feeding the MXU, and the product accumulates in f32. The quantized
 operands never round-trip to HBM — vs. the naive "quantize whole tensor,
 then matmul" graph this removes one full read+write of both operands.
 
+What the MXU is fed. A grid value is ``s * q`` with ``q = round(xc / s)``
+and ``s = 2^(p - b - mant)``; the bias ``b`` holds ``log2(alpha)``, so ``s``
+carries alpha's full f32 mantissa and ``s * q`` is not a bf16 value. The
+kernels factor the scale out (:func:`_codes`): ``s * q = sigma * c`` with
+the integer-valued code ``c = q * 2^(p - p_top)`` (at most ``mant + 2``
+significant bits, exponent in ``[1 - p_top, 0]``: exact in bf16) and one
+scalar ``sigma = 2^(p_top - b - mant)`` per operand, ``p_top = 2^exp - 1``
+the top of the format's exponent range. The MXU multiplies codes in ONE
+bf16 pass (each product is exact in f32, the accumulation is f32) and the
+accumulated tile is scaled by the operands' sigmas once. The rounding
+decisions ``q`` are the kernels' as before; only the dequantized value's
+representation moves, by ``exp2``'s own last-bit differences between
+arguments an integer apart (~2^-20 relative, 2^-19 at the clip floor).
+The backward kernels feed the cotangent ``g`` to the MXU in its own
+dtype: bf16 (every LM here) beside bf16 codes, and f32 beside f32 codes
+when ``g`` is f32 (:func:`mxu_dtype`), the f32 dot it had before.
+
 Blocking: (bm x bk) @ (bk x bn) with all three dims multiples of 128 to
 match the 128x128 MXU systolic array; the K grid axis is innermost and
 accumulates into the revisited output tile (standard Pallas matmul
-pattern). Default tiles use ~(256+256+128)KB of VMEM, leaving room for
-double buffering.
+pattern). Operands enter in their own dtype and are cast in VMEM. The
+operand tiles are re-quantized on every grid step, which bounds the
+kernels, so the default blocks are large (``_BLOCKS``).
 """
 from __future__ import annotations
 
@@ -26,13 +44,96 @@ from ..core.fp8 import _ALPHA_FLOOR, E4M3, FP8Format
 from .fp8_quant import _mant_const, _ste_terms, pad_to_blocks
 
 
-def _fake_quant(x, alpha, fmt: FP8Format):
-    b = 2.0 ** fmt.exp - jnp.log2(alpha) + _mant_const(fmt) - 1.0
+# The codes' dtype: every code is exact in it.
+CODE_DTYPE = jnp.dtype(jnp.bfloat16)
+
+
+def _code_exp_top(fmt: FP8Format) -> int:
+    """``p_top``: the largest exponent code ``p`` of ``fmt``'s grid."""
+    return 2 ** fmt.exp - 1
+
+
+def _code_bias(alpha, fmt: FP8Format):
+    """The exponent bias ``b`` of ``core.fp8.exponent_bias``, as the
+    kernels compute it."""
+    return 2.0 ** fmt.exp - jnp.log2(alpha) + _mant_const(fmt) - 1.0
+
+
+def _code_scale(alpha, fmt: FP8Format):
+    """``sigma = 2^(p_top - b - mant)``: the grid value of code 1."""
+    return jnp.exp2(_code_exp_top(fmt) - _code_bias(alpha, fmt) - fmt.mant)
+
+
+def _pow2_from(p, top: int):
+    """Exact ``2^(p - top)`` for integer-valued f32 ``p`` in [top - 126,
+    top], from exponent bits (no transcendental, so no rounding): ``p + (2^23
+    + 127 - top)`` is exact and holds ``p + 127 - top`` in its low mantissa
+    bits, which the shift moves into the exponent field. Two VPU ops."""
+    bits = jax.lax.bitcast_convert_type(p + float(2 ** 23 + 127 - top),
+                                        jnp.int32)
+    return jax.lax.bitcast_convert_type(bits << 23, jnp.float32)
+
+
+def _codes(x, alpha, fmt: FP8Format):
+    """FP8 fake-quant of f32 ``x`` as integer-valued codes ``c``, exact in
+    bf16: ``sigma * c`` is ``s * round(xc / s)`` up to ``exp2``'s last bits,
+    with ``sigma = _code_scale(alpha, fmt)``."""
+    b = _code_bias(alpha, fmt)
     xc = jnp.clip(x, -alpha, alpha)
     p = jnp.floor(jnp.log2(jnp.abs(xc)) + b)
     p = jnp.where(p > 1.0, p, 1.0)
     s = jnp.exp2(p - b - fmt.mant)
-    return s * jnp.round(xc / s)
+    return jnp.round(xc / s) * _pow2_from(p, _code_exp_top(fmt))
+
+
+def mxu_dtype(g_dtype) -> np.dtype:
+    """The dtype the backward kernels feed the MXU beside cotangent
+    ``g``: bf16 when ``g`` is bf16 (the codes are exact in bf16), else f32.
+    The forward always feeds bf16 codes."""
+    return CODE_DTYPE if jnp.dtype(g_dtype) == CODE_DTYPE \
+        else jnp.dtype(jnp.float32)
+
+
+# Default blocks (bm, bk, bn) of each kernel, by the itemsize of its
+# activation and cotangent operands. Every kernel re-quantizes one or both
+# operand tiles on each grid step, on the VPU, and that work, not the MXU,
+# bounds it: the more MXU work a step amortizes it over, the faster. The
+# 2-byte rows are the fastest blocks measured on a v5e, at (4096, 4096,
+# 12800), whose temporaries fit the kernel's VMEM (PERF.md). The 4-byte
+# rows only shorten M until the compiler fits them in VMEM; they were not
+# timed, since no workload on the chip hands the kernels f32.
+_BLOCKS = {
+    "qat_matmul": {2: (1024, 512, 1024), 4: (512, 512, 1024)},
+    "qat_matmul_dx": {2: (1024, 256, 512), 4: (512, 256, 512)},
+    "qat_matmul_dw": {2: (4096, 128, 512), 4: (1024, 128, 512)},
+}
+
+
+def _fit(block: int, dim: int) -> int:
+    """The block for an axis of length ``dim``, given the default
+    ``block`` (a multiple of 128): the whole axis if it is no longer, else
+    the largest multiple of 128 up to ``block`` that pads the axis to
+    within ``dim / 16`` of its shortest 128-multiple (6176 columns take
+    896, padded to 6272, not 1024, padded to 7168)."""
+    if dim <= block:
+        return dim
+    least = -(-dim // 128) * 128
+    return next(b for b in range(block, 0, -128)
+                if -(-dim // b) * b <= least + dim // 16)
+
+
+def _blocks(kernel: str, dims, blocks, *operands) -> tuple[int, int, int]:
+    """(bm, bk, bn) for an (m, k) @ (k, n) product: each given block (none
+    longer than its axis), else the kernel's default for the widest of
+    ``operands``, fitted to its axis (:func:`_fit`)."""
+    wide = max(jnp.dtype(o.dtype).itemsize for o in operands) > 2
+    default = _BLOCKS[kernel][4 if wide else 2]
+    return tuple(_fit(d, dim) if b is None else min(b, dim)
+                 for b, d, dim in zip(blocks, default, dims))
+
+
+def _clip_scalar(c):
+    return jnp.reshape(jnp.maximum(c.astype(jnp.float32), _ALPHA_FLOOR), (1, 1))
 
 
 def _qat_matmul_kernel(x_ref, w_ref, beta_ref, alpha_ref, o_ref, *, fmt, n_k):
@@ -42,11 +143,15 @@ def _qat_matmul_kernel(x_ref, w_ref, beta_ref, alpha_ref, o_ref, *, fmt, n_k):
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    xq = _fake_quant(x_ref[...].astype(jnp.float32), beta_ref[0, 0], fmt)
-    wq = _fake_quant(w_ref[...].astype(jnp.float32), alpha_ref[0, 0], fmt)
-    o_ref[...] += jnp.dot(
-        xq, wq, preferred_element_type=jnp.float32
-    ).astype(o_ref.dtype)
+    beta, alpha = beta_ref[0, 0], alpha_ref[0, 0]
+    cx = _codes(x_ref[...].astype(jnp.float32), beta, fmt)
+    cw = _codes(w_ref[...].astype(jnp.float32), alpha, fmt)
+    o_ref[...] += jnp.dot(cx.astype(CODE_DTYPE), cw.astype(CODE_DTYPE),
+                          preferred_element_type=jnp.float32)
+
+    @pl.when(k == n_k - 1)
+    def _scale():
+        o_ref[...] *= _code_scale(beta, fmt) * _code_scale(alpha, fmt)
 
 
 @functools.partial(
@@ -58,15 +163,15 @@ def qat_matmul(
     beta: jax.Array,    # activation clip (scalar)
     alpha: jax.Array,   # weight clip (scalar)
     fmt: FP8Format = E4M3,
-    bm: int = 256,
-    bk: int = 512,
-    bn: int = 256,
+    bm: int | None = None,
+    bk: int | None = None,
+    bn: int | None = None,
     interpret: bool = True,
 ) -> jax.Array:
     m, k = x.shape
     k2, n = w.shape
     assert k == k2, (x.shape, w.shape)
-    bm, bk, bn = min(bm, m), min(bk, k), min(bn, n)
+    bm, bk, bn = _blocks("qat_matmul", (m, k, n), (bm, bk, bn), x)
     # zero-pad the contraction axis: out-of-bounds K tiles would otherwise
     # accumulate garbage into in-bounds output rows (see pad_to_blocks)
     xp = pad_to_blocks(x, bm, bk)
@@ -88,8 +193,7 @@ def qat_matmul(
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mp, np_), jnp.float32),
         interpret=interpret,
-    )(xp, wp, jnp.reshape(jnp.maximum(beta.astype(jnp.float32), _ALPHA_FLOOR), (1, 1)),
-      jnp.reshape(jnp.maximum(alpha.astype(jnp.float32), _ALPHA_FLOOR), (1, 1)))
+    )(xp, wp, _clip_scalar(beta), _clip_scalar(alpha))
     return out[:m, :n].astype(x.dtype)
 
 
@@ -101,18 +205,35 @@ def qat_matmul(
 #   dbeta  = sum d/dxq * [sign(x) 1{|x|>beta} + (q-y) s / beta]
 #   dw, dalpha symmetrically.
 #
-# Both kernels RE-quantize the saved FP32 operand tile in VMEM (cheaper than
-# round-tripping the quantized copies through HBM) and accumulate the matmul
-# over the contraction grid axis into the revisited output tile; the mask /
-# clip-routing epilogue runs once, on the last contraction step. The scalar
-# clip-value cotangent accumulates across the whole grid into a revisited
-# (1, 1) SMEM block (Mosaic stores scalars only to SMEM; sequential grid =>
-# race-free; cheap partial reduction).
+# Both kernels RE-quantize the saved operand tile in VMEM (cheaper than
+# round-tripping the quantized copies through HBM) to its codes, feed the
+# MXU ``g`` and the codes in ``mxu_dtype(g.dtype)`` (contracting over the
+# shared axis with ``dot_general``, no transposed copy), and accumulate over
+# the contraction grid axis into the revisited output tile; the epilogue
+# scales by the operand's sigma and runs the mask / clip-routing terms
+# once, on the last contraction step. The scalar clip-value cotangent
+# accumulates across the whole grid into a revisited (1, 1) SMEM block
+# (Mosaic stores scalars only to SMEM; sequential grid => race-free; cheap
+# partial reduction).
 # ---------------------------------------------------------------------------
+
+_NT = (((1,), (1,)), ((), ()))     # a @ b^T
+_TN = (((0,), (0,)), ((), ()))     # a^T @ b
+
+
+def _ste_epilogue(acc, v, clip, fmt):
+    """(cotangent of v, partial clip cotangent) from the accumulated
+    cotangent ``acc`` of v's grid value: the STE clip mask and routing."""
+    inside, s, y = _ste_terms(v, clip, fmt)
+    q = jnp.round(y)
+    partial = jnp.sum(
+        acc * (jnp.sign(v) * (1.0 - inside) + (q - y) * s / clip)
+    )
+    return acc * inside, partial
 
 
 def _qat_matmul_dx_kernel(g_ref, w_ref, x_ref, beta_ref, alpha_ref,
-                          gx_ref, gb_ref, *, fmt, n_j):
+                          gx_ref, gb_ref, *, fmt, n_j, mxu):
     i, k, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
 
     @pl.when(j == 0)
@@ -123,25 +244,23 @@ def _qat_matmul_dx_kernel(g_ref, w_ref, x_ref, beta_ref, alpha_ref,
     def _init_gb():
         gb_ref[0, 0] = jnp.float32(0.0)
 
-    wq = _fake_quant(w_ref[...].astype(jnp.float32), alpha_ref[0, 0], fmt)
-    g = g_ref[...].astype(jnp.float32)
-    gx_ref[...] += jnp.dot(g, wq.T, preferred_element_type=jnp.float32)
+    alpha = alpha_ref[0, 0]
+    cw = _codes(w_ref[...].astype(jnp.float32), alpha, fmt)
+    gx_ref[...] += jax.lax.dot_general(
+        g_ref[...].astype(mxu), cw.astype(mxu), _NT,
+        preferred_element_type=jnp.float32)
 
     @pl.when(j == n_j - 1)
     def _epilogue():
-        x = x_ref[...].astype(jnp.float32)
-        beta = beta_ref[0, 0]
-        inside, s, y = _ste_terms(x, beta, fmt)
-        q = jnp.round(y)
-        gxq = gx_ref[...]
-        gb_ref[0, 0] += jnp.sum(
-            gxq * (jnp.sign(x) * (1.0 - inside) + (q - y) * s / beta)
-        )
-        gx_ref[...] = gxq * inside
+        acc = gx_ref[...] * _code_scale(alpha, fmt)
+        gx, partial = _ste_epilogue(acc, x_ref[...].astype(jnp.float32),
+                                    beta_ref[0, 0], fmt)
+        gb_ref[0, 0] += partial
+        gx_ref[...] = gx
 
 
 def _qat_matmul_dw_kernel(g_ref, x_ref, w_ref, beta_ref, alpha_ref,
-                          gw_ref, ga_ref, *, fmt, n_i):
+                          gw_ref, ga_ref, *, fmt, n_i, mxu):
     k, j, i = pl.program_id(0), pl.program_id(1), pl.program_id(2)
 
     @pl.when(i == 0)
@@ -152,21 +271,19 @@ def _qat_matmul_dw_kernel(g_ref, x_ref, w_ref, beta_ref, alpha_ref,
     def _init_ga():
         ga_ref[0, 0] = jnp.float32(0.0)
 
-    xq = _fake_quant(x_ref[...].astype(jnp.float32), beta_ref[0, 0], fmt)
-    g = g_ref[...].astype(jnp.float32)
-    gw_ref[...] += jnp.dot(xq.T, g, preferred_element_type=jnp.float32)
+    beta = beta_ref[0, 0]
+    cx = _codes(x_ref[...].astype(jnp.float32), beta, fmt)
+    gw_ref[...] += jax.lax.dot_general(
+        cx.astype(mxu), g_ref[...].astype(mxu), _TN,
+        preferred_element_type=jnp.float32)
 
     @pl.when(i == n_i - 1)
     def _epilogue():
-        w = w_ref[...].astype(jnp.float32)
-        alpha = alpha_ref[0, 0]
-        inside, s, y = _ste_terms(w, alpha, fmt)
-        q = jnp.round(y)
-        gwq = gw_ref[...]
-        ga_ref[0, 0] += jnp.sum(
-            gwq * (jnp.sign(w) * (1.0 - inside) + (q - y) * s / alpha)
-        )
-        gw_ref[...] = gwq * inside
+        acc = gw_ref[...] * _code_scale(beta, fmt)
+        gw, partial = _ste_epilogue(acc, w_ref[...].astype(jnp.float32),
+                                    alpha_ref[0, 0], fmt)
+        ga_ref[0, 0] += partial
+        gw_ref[...] = gw
 
 
 @functools.partial(
@@ -179,24 +296,25 @@ def qat_matmul_dx(
     beta: jax.Array,
     alpha: jax.Array,
     fmt: FP8Format = E4M3,
-    bm: int = 256,
-    bk: int = 256,
-    bn: int = 256,
+    bm: int | None = None,
+    bk: int | None = None,
+    bn: int | None = None,
     interpret: bool = True,
 ) -> tuple[jax.Array, jax.Array]:
     """Backward wrt activations: ``(dL/dx, dL/dbeta)``."""
     m, k = x.shape
     _, n = w.shape
-    bm, bk, bn = min(bm, m), min(bk, k), min(bn, n)
-    gp = pad_to_blocks(g.astype(jnp.float32), bm, bn)
-    wp = pad_to_blocks(w.astype(jnp.float32), bk, bn)
-    xp = pad_to_blocks(x.astype(jnp.float32), bm, bk)
+    bm, bk, bn = _blocks("qat_matmul_dx", (m, k, n), (bm, bk, bn), g, x)
+    gp = pad_to_blocks(g, bm, bn)
+    wp = pad_to_blocks(w, bk, bn)
+    xp = pad_to_blocks(x, bm, bk)
     mp, kp = xp.shape
     np_ = wp.shape[1]
     grid = (mp // bm, kp // bk, np_ // bn)
     scalar = pl.BlockSpec((1, 1), lambda i, kk, j: (0, 0))
     gx, gb = pl.pallas_call(
-        functools.partial(_qat_matmul_dx_kernel, fmt=fmt, n_j=grid[2]),
+        functools.partial(_qat_matmul_dx_kernel, fmt=fmt, n_j=grid[2],
+                          mxu=mxu_dtype(g.dtype)),
         grid=grid,
         name="qat_matmul_dx",
         in_specs=[
@@ -216,8 +334,7 @@ def qat_matmul_dx(
             jax.ShapeDtypeStruct((1, 1), jnp.float32),
         ],
         interpret=interpret,
-    )(gp, wp, xp, jnp.reshape(jnp.maximum(beta.astype(jnp.float32), _ALPHA_FLOOR), (1, 1)),
-      jnp.reshape(jnp.maximum(alpha.astype(jnp.float32), _ALPHA_FLOOR), (1, 1)))
+    )(gp, wp, xp, _clip_scalar(beta), _clip_scalar(alpha))
     return gx[:m, :k].astype(x.dtype), gb.reshape(jnp.shape(beta))
 
 
@@ -231,24 +348,25 @@ def qat_matmul_dw(
     beta: jax.Array,
     alpha: jax.Array,
     fmt: FP8Format = E4M3,
-    bm: int = 256,
-    bk: int = 256,
-    bn: int = 256,
+    bm: int | None = None,
+    bk: int | None = None,
+    bn: int | None = None,
     interpret: bool = True,
 ) -> tuple[jax.Array, jax.Array]:
     """Backward wrt weights: ``(dL/dw, dL/dalpha)``."""
     m, k = x.shape
     _, n = w.shape
-    bm, bk, bn = min(bm, m), min(bk, k), min(bn, n)
-    gp = pad_to_blocks(g.astype(jnp.float32), bm, bn)
-    xp = pad_to_blocks(x.astype(jnp.float32), bm, bk)
-    wp = pad_to_blocks(w.astype(jnp.float32), bk, bn)
+    bm, bk, bn = _blocks("qat_matmul_dw", (m, k, n), (bm, bk, bn), g, x)
+    gp = pad_to_blocks(g, bm, bn)
+    xp = pad_to_blocks(x, bm, bk)
+    wp = pad_to_blocks(w, bk, bn)
     mp, kp = xp.shape
     np_ = wp.shape[1]
     grid = (kp // bk, np_ // bn, mp // bm)
     scalar = pl.BlockSpec((1, 1), lambda kk, j, i: (0, 0))
     gw, ga = pl.pallas_call(
-        functools.partial(_qat_matmul_dw_kernel, fmt=fmt, n_i=grid[2]),
+        functools.partial(_qat_matmul_dw_kernel, fmt=fmt, n_i=grid[2],
+                          mxu=mxu_dtype(g.dtype)),
         grid=grid,
         name="qat_matmul_dw",
         in_specs=[
@@ -268,6 +386,5 @@ def qat_matmul_dw(
             jax.ShapeDtypeStruct((1, 1), jnp.float32),
         ],
         interpret=interpret,
-    )(gp, xp, wp, jnp.reshape(jnp.maximum(beta.astype(jnp.float32), _ALPHA_FLOOR), (1, 1)),
-      jnp.reshape(jnp.maximum(alpha.astype(jnp.float32), _ALPHA_FLOOR), (1, 1)))
+    )(gp, xp, wp, _clip_scalar(beta), _clip_scalar(alpha))
     return gw[:k, :n].astype(w.dtype), ga.reshape(jnp.shape(alpha))

@@ -133,8 +133,7 @@ def dense(p: dict, name: str, x: Array, qcfg: QATConfig,
         alpha = _lsq_grad_scale(p[name + "_qa"], w.size, qcfg.fmt)
         x2 = x.reshape(-1, x.shape[-1])
         out = dispatch.qat_matmul(
-            x2.astype(jnp.float32), w.astype(jnp.float32), beta, alpha,
-            qcfg.fmt,
+            x2, w.astype(jnp.float32), beta, alpha, qcfg.fmt,
         )
         return out.reshape(*x.shape[:-1], w.shape[-1]).astype(COMPUTE_DTYPE)
     if act_site is not None and act_site in p:

@@ -16,7 +16,7 @@ import pytest
 from repro.core import fp8, wire
 from repro.core.fp8 import E4M3, E5M2
 from repro.core.qat import alpha_like
-from repro.kernels import dispatch
+from repro.kernels import dispatch, fp8_quant
 
 
 @pytest.fixture
@@ -32,6 +32,18 @@ def _rel_close(got, want, tol=1e-5):
     scale = max(np.max(np.abs(want)), 1e-6)
     err = np.max(np.abs(got - want)) / scale
     assert err <= tol, f"relative error {err:.3e} > {tol:g}"
+
+
+def _clip_close(got, want, v, clip, d, tol=1e-5):
+    """A clip cotangent is a sum of terms that cancel: ``d * t`` over the
+    elements of ``v``, with ``t`` the STE's clip routing beyond the clip and
+    rounding residual inside it. Compare relative to the sum of the terms'
+    magnitudes, which bounds what per-element round-off can move it by."""
+    inside, s, y = fp8_quant._ste_terms(v, clip, E4M3)
+    t = jnp.sign(v) * (1.0 - inside) + (jnp.round(y) - y) * s / clip
+    scale = float(jnp.sum(jnp.abs(d * t)))
+    err = abs(float(got) - float(want)) / scale
+    assert err <= tol, f"error {err:.3e} of the terms' L1 > {tol:g}"
 
 
 @pytest.mark.parametrize("fmt", [E4M3, E5M2])
@@ -92,33 +104,90 @@ def test_quant_rand_vjp_matches_autodiff(interpret_backend):
 # Alphas are scaled off max|w| so no element sits exactly on the clip
 # boundary, where jax.clip autodiff tie-splits the subgradient (0.5) while
 # the STE kernels use the closed form (1) — a measure-zero convention
-# difference, not an error (see dispatch docstring).
+# difference, not an error (see dispatch docstring). The bf16 cases are
+# the LMs' path: bf16 activations and cotangents, which the kernels feed
+# the MXU as bf16 beside the bf16 FP8 codes; dx comes back in x's dtype,
+# so it carries one bf16 rounding (2^-9 relative) more than the f32 oracle.
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 @pytest.mark.parametrize("m,k,n", [(96, 160, 64), (128, 128, 128),
                                    (64, 784, 32)])
-def test_qat_matmul_vjp_matches_autodiff(interpret_backend, m, k, n):
-    x = jax.random.normal(jax.random.PRNGKey(0), (m, k), jnp.float32) * 0.5
+def test_qat_matmul_vjp_matches_autodiff(interpret_backend, m, k, n, dtype):
+    x = (jax.random.normal(jax.random.PRNGKey(0), (m, k), jnp.float32)
+         * 0.5).astype(dtype)
     w = jax.random.normal(jax.random.PRNGKey(1), (k, n), jnp.float32) * 0.1
     beta = jnp.asarray(0.8, jnp.float32)
     alpha = jnp.asarray(0.6 * float(jnp.max(jnp.abs(w))), jnp.float32)
-    g = jax.random.normal(jax.random.PRNGKey(2), (m, n), jnp.float32)
+    g = jax.random.normal(jax.random.PRNGKey(2), (m, n),
+                          jnp.float32).astype(dtype)
 
     def oracle(x, w, beta, alpha):
         return jnp.sum(jnp.dot(
             fp8.quantize_det(x, beta), fp8.quantize_det(w, alpha),
             preferred_element_type=jnp.float32,
-        ) * g)
+        ) * g.astype(jnp.float32))
 
     gx_o, gw_o, gb_o, ga_o = jax.grad(oracle, argnums=(0, 1, 2, 3))(
-        x, w, beta, alpha
+        x.astype(jnp.float32), w, beta, alpha
     )
+    dispatch.QAT_MXU.clear()
     gx, gw, gb, ga = jax.grad(
-        lambda x, w, b, a: jnp.sum(dispatch.qat_matmul(x, w, b, a) * g),
+        lambda x, w, b, a: jnp.sum(
+            dispatch.qat_matmul(x, w, b, a).astype(jnp.float32)
+            * g.astype(jnp.float32)),
         argnums=(0, 1, 2, 3),
     )(x, w, beta, alpha)
-    _rel_close(gx, gx_o)
+    mxu = jnp.dtype(dtype).name
+    assert dict(dispatch.QAT_MXU) == {
+        "qat_matmul:bfloat16": 1, f"qat_matmul_dx:{mxu}": 1,
+        f"qat_matmul_dw:{mxu}": 1}
+    assert gx.dtype == dtype
     _rel_close(gw, gw_o)
-    _rel_close(gb, gb_o)
-    _rel_close(ga, ga_o)
+    if dtype == jnp.float32:
+        _rel_close(gx, gx_o)
+        _rel_close(gb, gb_o)
+        _rel_close(ga, ga_o)
+        return
+    # bf16 activations: dx carries its output rounding, and the clip sums
+    # are compared at the scale of their terms (bf16-valued activations
+    # clip into larger cancelling sums than the f32 cases' at this width)
+    _rel_close(gx, gx_o, 2.0 ** -8)
+    xf = x.astype(jnp.float32)
+    gf = g.astype(jnp.float32)
+    _clip_close(gb, gb_o, xf, beta, gf @ fp8.quantize_det(w, alpha).T)
+    _clip_close(ga, ga_o, w, alpha, fp8.quantize_det(xf, beta).T @ gf)
+
+
+def test_fused_dense_equals_the_f32_cast_call_site(interpret_backend):
+    """``models.common.dense`` hands the kernels its bf16 activations: the
+    output and dx equal those of the f32-cast call site it replaced
+    (``x2.astype(float32)`` into ``dispatch.qat_matmul``), after that call
+    site's bf16 rounding of the output and of dx."""
+    from repro.core.qat import QATConfig
+    from repro.models import common
+
+    k1, k2, k3 = jax.random.split(jax.random.PRNGKey(9), 3)
+    x = jax.random.normal(k1, (2, 48, 160), jnp.float32).astype(jnp.bfloat16)
+    w = jax.random.normal(k2, (160, 96), jnp.float32) * 0.08
+    g = jax.random.normal(k3, (2, 48, 96), jnp.float32).astype(jnp.bfloat16)
+    p = {"w": w, "w_qa": jnp.asarray(0.6 * float(jnp.max(jnp.abs(w)))),
+         "x_qb": jnp.asarray(1.7, jnp.float32)}
+    qcfg = QATConfig()
+    assert common._fused_dense_ok(p, "w", x, qcfg, "x_qb")
+
+    def f32_call_site(x):
+        beta = common._lsq_grad_scale(p["x_qb"], x.size, qcfg.fmt)
+        alpha = common._lsq_grad_scale(p["w_qa"], w.size, qcfg.fmt)
+        x2 = x.reshape(-1, x.shape[-1])
+        out = dispatch.qat_matmul(x2.astype(jnp.float32), w, beta, alpha,
+                                  qcfg.fmt)
+        return out.reshape(*x.shape[:-1], w.shape[-1]).astype(jnp.bfloat16)
+
+    out, vjp = jax.vjp(lambda x: common.dense(p, "w", x, qcfg, "x_qb"), x)
+    out_o, vjp_o = jax.vjp(f32_call_site, x)
+    (gx,), (gx_o,) = vjp(g), vjp_o(g)
+    assert out.dtype == gx.dtype == jnp.bfloat16
+    _rel_close(out, out_o)
+    _rel_close(gx, gx_o)
 
 
 def test_qat_matmul_forward_matches_composition(interpret_backend):

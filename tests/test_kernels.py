@@ -5,7 +5,7 @@ import numpy as np
 import pytest
 
 from repro.core import fp8
-from repro.core.fp8 import E4M3, E5M2
+from repro.core.fp8 import _ALPHA_FLOOR, E4M3, E5M2
 from repro.kernels import fp8_matmul, fp8_quant, ops, ref
 
 SHAPES = [(8, 128), (16, 256), (256, 512), (300, 200), (1, 128), (129, 384)]
@@ -153,3 +153,110 @@ def test_uniform_from_bits_equals_direct_cast():
         1.0 / 4294967296.0)
     np.testing.assert_array_equal(got, want)
     assert got.max() <= 1.0
+
+
+# Clips off a power of two, on one, and at the floor, where the grid's
+# scales and the codes' exponents reach their extremes.
+CODE_CLIPS = [0.37, 3.7, 0.25, 1.0, 8.0, _ALPHA_FLOOR]
+
+
+def _fake_quant_unfactored(x, alpha, fmt):
+    """The matmul kernels' quantizer with the scale left in: ``(p, q,
+    s * q)`` in f32, as the kernels computed it before the codes."""
+    b = 2.0 ** fmt.exp - jnp.log2(alpha) + fp8_quant._mant_const(fmt) - 1.0
+    xc = jnp.clip(x, -alpha, alpha)
+    p = jnp.floor(jnp.log2(jnp.abs(xc)) + b)
+    p = jnp.where(p > 1.0, p, 1.0)
+    s = jnp.exp2(p - b - fmt.mant)
+    q = jnp.round(xc / s)
+    return p, q, s * q, p - b - fmt.mant
+
+
+def _code_inputs(alpha):
+    """Every binade of the grid and beyond the clip, zeros, +-alpha."""
+    k1, k2 = jax.random.split(jax.random.PRNGKey(12))
+    mag = jnp.exp2(jax.random.uniform(k1, (64, 128), minval=-34.0,
+                                      maxval=1.0))
+    x = jax.random.normal(k2, (64, 128)) * mag * alpha
+    edges = jnp.asarray([0.0, alpha, -alpha, 2 * alpha, -2 * alpha],
+                        jnp.float32)
+    return x.at[0, :5].set(edges)
+
+
+@pytest.mark.parametrize("alpha", CODE_CLIPS)
+@pytest.mark.parametrize("fmt", FMTS)
+def test_matmul_codes_factor_the_scale(fmt, alpha):
+    """The codes the QAT matmul feeds the MXU: bf16-exact, the same
+    rounding decisions as the unfactored quantizer, and ``sigma * c`` its
+    grid value up to exp2's own inconsistency under an integer shift of
+    its argument."""
+    alpha = jnp.float32(alpha)
+    x = _code_inputs(alpha)
+    codes = jax.jit(fp8_matmul._codes, static_argnums=2)
+    c = np.asarray(codes(x, alpha, fmt))
+    sigma = np.float32(fp8_matmul._code_scale(alpha, fmt))
+    p, q, fq, arg = (np.asarray(v) for v in _fake_quant_unfactored(
+        x, alpha, fmt))
+    p_top = 2 ** fmt.exp - 1
+
+    # exact in bf16, bit for bit
+    back = np.asarray(jnp.asarray(c).astype(jnp.bfloat16).astype(jnp.float32))
+    np.testing.assert_array_equal(back.view(np.uint32), c.view(np.uint32))
+    # c = q * 2^(p - p_top), q = round(xc / s) the unfactored decision
+    np.testing.assert_array_equal(
+        c, np.ldexp(q, (p - p_top).astype(np.int32)).astype(np.float32))
+    assert np.abs(q).max() <= 2 ** (fmt.mant + 1)
+
+    # sigma * c against s * q: the two differ only by exp2 evaluated at
+    # arguments an integer apart (plus one rounding of each product);
+    # measure that inconsistency of exp2 itself at the arguments used
+    nz = fq != 0
+    np.testing.assert_array_equal(c != 0, nz)
+    rel = np.abs(sigma * c[nz] - fq[nz]) / np.abs(fq[nz])
+    shifted = np.asarray(jnp.exp2(jnp.asarray(arg[nz]))) / (
+        sigma * np.ldexp(np.float32(1), (p[nz] - p_top).astype(np.int32)))
+    exp2_gap = np.abs(shifted.astype(np.float64) - 1.0).max()
+    assert rel.max() <= exp2_gap + 2.0 ** -23, (rel.max(), exp2_gap)
+    assert rel.max() <= 2.0 ** -18, rel.max()
+    if alpha >= 2.0 ** -8 and fmt == E4M3:
+        assert rel.max() <= 2.0 ** -20, rel.max()
+
+    # sigma_x * sigma_w and every code stay finite and normal in f32
+    tiny = np.finfo(np.float32).tiny
+    assert np.isfinite(sigma * sigma) and sigma * sigma >= tiny
+    assert np.all(np.abs(c[nz]) >= tiny) and np.all(np.isfinite(c))
+    assert np.abs(c).max() <= 2.0 ** (fmt.mant + 1)
+
+
+@pytest.mark.parametrize("fmt", FMTS)
+def test_matmul_code_scale_is_code_one(fmt):
+    """``sigma`` is the top binade's grid step: alpha over the largest
+    mantissa count, ``alpha / (2^(mant+1) - 1)``."""
+    for alpha in CODE_CLIPS:
+        sigma = float(fp8_matmul._code_scale(jnp.float32(alpha), fmt))
+        want = np.float32(alpha) / (2.0 ** (fmt.mant + 1) - 1.0)
+        assert abs(sigma - want) <= 2.0 ** -18 * want, (alpha, sigma, want)
+
+
+@pytest.mark.parametrize("block, dim, want", [
+    (1024, 6176, 896),      # the Granite head's vocabulary slice
+    (1024, 12800, 1024),    # 13312: within a sixteenth of 12800
+    (512, 6176, 512),       # 6656: within a sixteenth of 6272
+    (4096, 5000, 2688),     # 5376, not 8192
+    (1024, 784, 784),       # no longer than the block: the whole axis
+    (1024, 4096, 1024),
+])
+def test_matmul_block_fits_its_axis(block, dim, want):
+    assert fp8_matmul._fit(block, dim) == want
+
+
+def test_matmul_block_fit_bounds_the_padding():
+    """Every fitted block is a multiple of 128 up to the default (or the
+    whole axis) and pads the axis to within a sixteenth of its shortest
+    128-multiple."""
+    for block in (512, 1024, 4096):
+        for dim in range(block + 1, 4 * block + 300, 37):
+            b = fp8_matmul._fit(block, dim)
+            least = -(-dim // 128) * 128
+            assert b % 128 == 0 and b <= block, (block, dim, b)
+            assert -(-dim // b) * b <= least + dim // 16, (block, dim, b)

@@ -5,7 +5,8 @@ Each kernel is AOT-compiled at real widths for one chip of a described
 and must lower to a ``tpu_custom_call``. This catches what interpret mode
 cannot: casts, scalar stores and reshapes Mosaic refuses. Widths: a
 4096 x 1024 wire/plane tile layout, and TinyLlama's MLP matmul
-(2048 x 2048 @ 2048 x 5632). A small federated round compiles the same
+(2048 x 2048 @ 2048 x 5632), and the QAT matmul with bf16 activations at
+Granite-3-8B's widths. A small federated round compiles the same
 way: its kernels keep the names the benchmark's readers match
 (``bench/kernels.py``), and each runs under a stage scope of the round.
 
@@ -13,6 +14,7 @@ The topology is described inside a module fixture, never at import: only
 one process at a time may hold the TPU library, and every test worker
 imports this file.
 """
+import collections
 import os
 import re
 
@@ -23,7 +25,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.core.fp8 import E4M3, FP4_E2M1
-from repro.kernels import fp8_matmul, fp8_quant
+from repro.kernels import dispatch, fp8_matmul, fp8_quant
 
 R, LANE = 4096, fp8_quant.WIRE_LANE
 M, K, N = 2048, 2048, 5632
@@ -139,7 +141,35 @@ def _cases(S):
             lambda g, a, b, be, al: fp8_matmul.qat_matmul_dw(
                 g, a, b, be, al, **kw),
             (mat, x, w, scalar, scalar)),
+        **_bf16_matmul_cases(S, kw),
     }
+
+
+# Granite-3-8B's projections at 4096 tokens with bf16 activations and
+# cotangents, the path whose MXU operands are bf16: attention, MLP, and
+# the 6176-wide vocabulary slice of the head (not a multiple of the block).
+BF16_WIDTHS = ((4096, 4096), (4096, 12800), (4096, 6176))
+
+
+def _bf16_matmul_cases(S, kw):
+    bf, f32 = jnp.bfloat16, jnp.float32
+    scalar = S((), f32)
+    cases = {}
+    for k, n in BF16_WIDTHS:
+        x, w, g = S((4096, k), bf), S((k, n), f32), S((4096, n), bf)
+        tag = f"bf16_{k}x{n}"
+        cases[f"qat_matmul_{tag}"] = (
+            lambda a, b, be, al: fp8_matmul.qat_matmul(a, b, be, al, **kw),
+            (x, w, scalar, scalar))
+        cases[f"qat_matmul_dx_{tag}"] = (
+            lambda g, a, b, be, al: fp8_matmul.qat_matmul_dx(
+                g, a, b, be, al, **kw),
+            (g, x, w, scalar, scalar))
+        cases[f"qat_matmul_dw_{tag}"] = (
+            lambda g, a, b, be, al: fp8_matmul.qat_matmul_dw(
+                g, a, b, be, al, **kw),
+            (g, x, w, scalar, scalar))
+    return cases
 
 
 CASES = sorted(_cases(lambda shape, dtype: None))
@@ -198,6 +228,7 @@ def round_kernels(one_chip, no_persistent_cache):
                     batch_size=2, comm_mode="rand", qat=QATConfig())
     with pytest.MonkeyPatch.context() as mp:
         mp.setenv("REPRO_KERNEL_BACKEND", "pallas")
+        before = collections.Counter(dispatch.QAT_MXU)
         eng = RoundEngine(loss, optim.adamw(1e-3, weight_decay=0.01), fed)
         state = eng.init(model.init(jax.random.PRNGKey(0)))
         tokens = np.zeros((4, 2, 64), np.int32)
@@ -206,6 +237,11 @@ def round_kernels(one_chip, no_persistent_cache):
         shapes = jax.tree.map(lambda x: jax.ShapeDtypeStruct(
             np.shape(x), jnp.result_type(x), sharding=one_chip), args)
         text = jax.jit(eng.round_fn).lower(*shapes).compile().as_text()
+    # the LM's bf16 activations reach all three kernels as bf16
+    traced = dispatch.QAT_MXU - before
+    assert {key.split(":")[0] for key in traced} == {
+        "qat_matmul", "qat_matmul_dx", "qat_matmul_dw"}, traced
+    assert {key.split(":")[1] for key in traced} == {"bfloat16"}, traced
     return list(_kernel_calls(text))
 
 
