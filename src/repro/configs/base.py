@@ -24,9 +24,20 @@ class MLAConfig:
 
 @dataclasses.dataclass(frozen=True)
 class MoEConfig:
+    """Routed experts. The router always scores all ``n_experts``; a
+    model holds the ``experts_here`` of them from ``first_expert`` on (all
+    when None), as one chip of an expert-parallel deployment does, and
+    computes their part of each layer's result."""
+
     n_experts: int = 8
     top_k: int = 2
-    capacity_factor: float = 1.25
+    experts_here: int | None = None
+    first_expert: int = 0
+
+    @property
+    def held(self) -> int:
+        return self.n_experts if self.experts_here is None \
+            else self.experts_here
 
 
 @dataclasses.dataclass(frozen=True)
@@ -111,7 +122,7 @@ class ModelConfig:
                     + H * m.v_head_dim * D)
         ffn = 3 * D * F
         if self.moe:
-            ffn = self.moe.n_experts * 3 * D * F + D * self.moe.n_experts
+            ffn = self.moe.held * 3 * D * F + D * self.moe.n_experts
         per = attn + ffn
         if self.family == "hybrid":
             # recurrent layers replace attention with RG-LRU machinery
@@ -122,12 +133,13 @@ class ModelConfig:
         return total
 
     def active_param_count(self) -> int:
-        """Per-token active params (MoE: only top_k experts) for 6*N*D FLOPs."""
+        """Per-token active params (MoE: the top_k experts, of which the
+        held share on average) for 6*N*D FLOPs."""
         if not self.moe:
             return self.param_count()
-        D, F, L = self.d_model, self.d_ff, self.n_layers
-        dense_like = self.param_count() - L * (self.moe.n_experts - self.moe.top_k) * 3 * D * F
-        return dense_like
+        m, D, F, L = self.moe, self.d_model, self.d_ff, self.n_layers
+        idle = m.held - m.top_k * m.held / m.n_experts
+        return int(self.param_count() - L * idle * 3 * D * F)
 
     def replace(self, **kw) -> "ModelConfig":
         return dataclasses.replace(self, **kw)
@@ -172,8 +184,7 @@ def reduced(cfg: ModelConfig) -> ModelConfig:
                               qk_rope_dim=8, v_head_dim=16)
     if cfg.moe:
         kw["moe"] = MoEConfig(n_experts=min(cfg.moe.n_experts, 4),
-                              top_k=min(cfg.moe.top_k, 2),
-                              capacity_factor=cfg.moe.capacity_factor)
+                              top_k=min(cfg.moe.top_k, 2))
     if cfg.ssm:
         kw["ssm"] = SSMConfig(d_state=16, expand=2, head_dim=16, chunk=16)
     if cfg.rglru:

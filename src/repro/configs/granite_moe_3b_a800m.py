@@ -1,5 +1,6 @@
-"""Granite-MoE 3B-a800m — 40-expert top-8 MoE
-[hf:ibm-granite/granite-3.0 MoE family; hf]."""
+"""Granite-3.0-3B-A800M — 40 experts of width 512, top-8, GQA 24/8 heads
+of 64 [https://huggingface.co/ibm-granite/granite-3.0-3b-a800m-base,
+model type ``granitemoe``]."""
 from .base import ModelConfig, MoEConfig
 
 CONFIG = ModelConfig(
@@ -13,7 +14,7 @@ CONFIG = ModelConfig(
     vocab=49408,  # 49155 padded to /256 for TP (std TPU vocab padding)
     head_dim=64,
     attention="full",
-    moe=MoEConfig(n_experts=40, top_k=8, capacity_factor=1.25),
+    moe=MoEConfig(n_experts=40, top_k=8),
     rope_theta=10000.0,
     act="silu",
 )

@@ -14,7 +14,7 @@ CONFIG = ModelConfig(
     head_dim=128,
     attention="swa",
     window=4096,
-    moe=MoEConfig(n_experts=8, top_k=2, capacity_factor=1.25),
+    moe=MoEConfig(n_experts=8, top_k=2),
     rope_theta=1000000.0,
     act="silu",
     subquadratic=True,   # SWA => bounded decode cache

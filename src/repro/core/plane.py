@@ -12,9 +12,10 @@ Layout
 The plane is built at **alpha-segment** granularity: one segment per
 clipping *scalar*, i.e. one per quantized tensor, or one per layer slab for
 stacked scanned parameters whose clipping value has shape
-``(L, 1, ..., 1)``. Each segment is zero-padded to a whole number of
-``(LANE,)`` rows, so every row belongs to exactly one clipping value and
-the kernels take alpha as a ``(n_rows, 1)`` per-row *column* — 1/LANE the
+``(L, 1, ..., 1)`` (one per (layer, expert) slab for ``(L, E, 1, 1)``).
+Each segment is zero-padded to a whole number of ``(LANE,)`` rows, so
+every row belongs to exactly one clipping value and the kernels take
+alpha as a ``(n_rows, 1)`` per-row *column* — 1/LANE the
 operand traffic of a full tile, broadcast in-kernel. (This is where the
 plane deliberately differs from ``core.wire``'s payload layout, which packs
 each leaf contiguously so codes slice back to exact wire bytes; here the
@@ -156,11 +157,14 @@ def make_plane_spec(params: PyTree) -> PlaneSpec:
         n_seg_leaf = int(nelem(tuple(a_leaf.shape)))
         if n_seg_leaf > 1:
             # stacked scanned parameter: alpha (L, 1, ..., 1) pairs layer
-            # slabs of the (L, ...) weight — one segment per layer
-            if leaf.shape[0] != n_seg_leaf:
+            # slabs of the (L, ...) weight — one segment per layer; an
+            # expert stack's (L, E, 1, 1) one per (layer, expert) slab
+            lead = next(i for i in range(len(a_leaf.shape) + 1)
+                        if all(d == 1 for d in a_leaf.shape[i:]))
+            if tuple(leaf.shape[:lead]) != tuple(a_leaf.shape[:lead]):
                 raise ValueError(
                     f"{name}: stacked alpha {a_leaf.shape} does not pair "
-                    f"leading axis of weight {leaf.shape}"
+                    f"leading axes of weight {leaf.shape}"
                 )
         size = int(leaf.size) // n_seg_leaf
         q_slots.append(index[name])

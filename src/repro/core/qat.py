@@ -9,7 +9,9 @@ clip —
 
 For stacked (scanned-over-layers) parameters of shape ``(L, ...)`` the
 clipping value has shape ``(L, 1, ..., 1)`` so it broadcasts per layer —
-"per-tensor" in the paper's sense means per (layer, tensor).
+"per-tensor" in the paper's sense means per (layer, tensor). Stacked
+expert weights ``(L, E, d_in, d_out)`` carry one per (layer, expert),
+``(L, E, 1, 1)``.
 
 This keeps alphas/betas trainable by the same optimizer as the weights
 (the paper treats them as learnable parameters), makes them scan-sliceable,
@@ -69,10 +71,12 @@ def is_clip_key(name: str) -> bool:
     return name.endswith(QA_SUFFIX) or name.endswith(QB_SUFFIX)
 
 
-def alpha_like(w: Array, stacked: bool = False) -> Array:
-    """Paper's alpha init: per-tensor max |w| (per layer when stacked)."""
+def alpha_like(w: Array, stacked: bool = False, lead: int = 1) -> Array:
+    """Paper's alpha init: per-tensor max |w|; when stacked, one per index
+    of the ``lead`` leading axes (the layer, or the layer and the expert:
+    shape ``(L, E, 1, 1)``)."""
     if stacked:
-        axes = tuple(range(1, w.ndim))
+        axes = tuple(range(lead, w.ndim))
         return jnp.max(jnp.abs(w), axis=axes, keepdims=True).astype(jnp.float32)
     return jnp.max(jnp.abs(w)).astype(jnp.float32)
 
@@ -149,8 +153,11 @@ def _grad_quant_bwd(fmt, margin, _res, g):
 _grad_quant.defvjp(_grad_quant_fwd, _grad_quant_bwd)
 
 
-def aq(x: Array, beta: Array, cfg: QATConfig) -> Array:
+def aq(x: Array, beta: Array, cfg: QATConfig, n: int | None = None) -> Array:
     """Fake-quantize an activation tensor (always deterministic, sep. clip beta).
+
+    LSQ scales the clip's gradient by the ``n`` elements it clips
+    (``x.size`` unless given: a site whose rows repeat counts them once).
 
     With ``cfg.bwd_fmt`` set, the site becomes the hybrid
     activation/gradient recipe: forward stays ``cfg.fmt`` (E4M3 QAT,
@@ -163,7 +170,7 @@ def aq(x: Array, beta: Array, cfg: QATConfig) -> Array:
     from ..kernels import dispatch
 
     # Activations are quantized symmetrically like weights (paper §2).
-    beta = _lsq_grad_scale(beta, x.size, cfg.fmt)
+    beta = _lsq_grad_scale(beta, x.size if n is None else n, cfg.fmt)
     out = dispatch.quantize_det(x, beta, cfg.fmt)
     if cfg.bwd_fmt is not None:
         out = _grad_quant(out, cfg.bwd_fmt, cfg.bwd_margin)

@@ -40,10 +40,13 @@ mask (1 to x) — gone after the first optimizer step.
 Shape contract: the fused quantizers require a single (per-tensor) clipping
 scalar; stacked per-layer clipping values of shape ``(L, 1, ..., 1)``
 dispatch to jnp (inside ``lax.scan`` over layers each slice is a scalar, so
-the scanned models do hit the kernels on TPU). A region that GSPMD
-partitions over a mesh traces on jnp too (:func:`gspmd_partitioned`). Both
-routes are static, decided at trace time and counted in ``JNP_ROUTES``; no
-dispatcher tries a kernel and falls back when it fails.
+the scanned models do hit the kernels on TPU). The grouped QAT matmul of
+the sparse experts takes its per-expert clips ``(G,)`` itself
+(:func:`qat_gmm`), so an expert stack's ``(E, 1, 1)`` clips route nothing
+to jnp. A region that GSPMD partitions over a mesh traces on jnp too
+(:func:`gspmd_partitioned`). Both routes are static, decided at trace
+time and counted in ``JNP_ROUTES``; no dispatcher tries a kernel and
+falls back when it fails.
 """
 from __future__ import annotations
 
@@ -72,9 +75,10 @@ _ENV = "REPRO_KERNEL_BACKEND"
 # ``gspmd_region`` — a region traced under :func:`gspmd_partitioned`.
 JNP_ROUTES: collections.Counter = collections.Counter()
 
-# Trace-time counts of the fused QAT-matmul kernels traced, by kernel and
-# the dtype of their MXU operands: ``qat_matmul:bfloat16``,
-# ``qat_matmul_dx:float32`` (see ``fp8_matmul.mxu_dtype``).
+# Trace-time counts of the fused QAT-matmul kernels traced, dense and
+# grouped, by kernel and the dtype of their MXU operands:
+# ``qat_matmul:bfloat16``, ``qat_matmul_dx:float32``,
+# ``qat_gmm_dw:bfloat16`` (see ``fp8_matmul.mxu_dtype``).
 QAT_MXU: collections.Counter = collections.Counter()
 
 _GSPMD = contextvars.ContextVar("repro_gspmd_region", default=False)
@@ -305,6 +309,87 @@ def qat_matmul(
         preferred_element_type=jnp.float32,
     )
     return out.astype(x.dtype)
+
+
+# ---------------------------------------------------------------------------
+# Grouped QAT matmul (sparse experts) — kernel-backed custom VJP
+# ---------------------------------------------------------------------------
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _qat_gmm_kernel_ste(x, w, beta, alpha, offsets, fmt):
+    _, interp = _pallas_opts()
+    QAT_MXU["qat_gmm:" + fp8_matmul.CODE_DTYPE.name] += 1
+    return fp8_matmul.qat_gmm(x, w, beta, alpha, offsets, fmt=fmt,
+                              interpret=interp)
+
+
+def _qat_gmm_fwd(x, w, beta, alpha, offsets, fmt):
+    return (_qat_gmm_kernel_ste(x, w, beta, alpha, offsets, fmt),
+            (x, w, beta, alpha, offsets))
+
+
+def _qat_gmm_bwd(fmt, res, g):
+    x, w, beta, alpha, offsets = res
+    _, interp = _pallas_opts()
+    mxu = fp8_matmul.mxu_dtype(g.dtype).name
+    QAT_MXU["qat_gmm_dx:" + mxu] += 1
+    QAT_MXU["qat_gmm_dw:" + mxu] += 1
+    gx, gb = fp8_matmul.qat_gmm_dx(g, x, w, beta, alpha, offsets, fmt=fmt,
+                                   interpret=interp)
+    gw, ga = fp8_matmul.qat_gmm_dw(g, x, w, beta, alpha, offsets, fmt=fmt,
+                                   interpret=interp)
+    return (gx, gw, gb.astype(jnp.float32), ga.astype(jnp.float32),
+            _zero_bits_cotangent(offsets))
+
+
+_qat_gmm_kernel_ste.defvjp(_qat_gmm_fwd, _qat_gmm_bwd)
+
+
+def gmm(x: Array, w: Array, offsets: Array) -> Array:
+    """Grouped matmul in jnp: row r of group e is ``x[r] @ w[e]``, rows
+    past ``offsets[-1]`` zero, accumulated in f32.
+
+    The rows are cut into tiles of ``ceil(M / 2G)``, and each group
+    multiplies the tiles it covers by its own weight, masked to its rows:
+    the kernels' visit schedule, at most ``2G + G - 1`` visits of one tile
+    each. So the work is at most 1.5 times that of the M rows whatever the
+    groups' sizes, and each visit gathers its group's weight.
+    """
+    m, g, n = x.shape[0], w.shape[0], w.shape[2]
+    bm = -(-m // (2 * g))
+    tiles = -(-m // bm)
+    xt = jnp.pad(x, ((0, tiles * bm - m), (0, 0))).reshape(tiles, bm, -1)
+    offsets = offsets.astype(jnp.int32)
+    gids, mids, n_visits = fp8_matmul._gmm_schedule(offsets, tiles, bm)
+    rows = mids[:, None] * bm + jnp.arange(bm, dtype=jnp.int32)
+    own = ((jnp.arange(gids.shape[0]) < n_visits)[:, None]
+           & (rows >= offsets[gids][:, None])
+           & (rows < offsets[gids + 1][:, None]))
+    part = jnp.einsum("vmk,vkn->vmn",
+                      jnp.where(own[..., None], xt[mids], 0), w[gids],
+                      preferred_element_type=jnp.float32)
+    out = jnp.zeros((tiles, bm, n), jnp.float32).at[mids].add(part)
+    return out.reshape(tiles * bm, n)[:m]
+
+
+def qat_gmm(
+    x: Array, w: Array, beta: Array, alpha: Array, offsets: Array,
+    fmt: FP8Format = E4M3,
+) -> Array:
+    """Grouped ``Q_det(x[r]; beta) @ Q_det(w[e]; alpha[e])`` for the rows r
+    of each group e (``offsets``: (G + 1,) row offsets from 0, rows sorted
+    by group; rows past the last group's end give zero), on the kernels.
+
+    One kernel per pass (``kernels/fp8_matmul.py`` ``qat_gmm``, ``_dx``,
+    ``_dw``) fake-quantizes the operand tiles in VMEM as :func:`qat_matmul`
+    does, with each group's own weight clip; the backward returns the
+    activation clip's and every group's weight clip's cotangents. Each
+    kernel traced is counted in ``QAT_MXU``. The kernels run interpreted
+    off the chip; the model calls this only where :func:`use_kernels`
+    holds, and composes :func:`gmm` with the quantizers elsewhere.
+    """
+    return _qat_gmm_kernel_ste(x, w, beta, alpha, offsets, fmt)
 
 
 # ---------------------------------------------------------------------------

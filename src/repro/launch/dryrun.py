@@ -124,7 +124,7 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool,
             # grad-accumulation microbatching: target <=16k tokens per
             # device per microbatch (bounds live activations + scan stacks;
             # MoE halves the target — dispatch buffers scale with tokens x
-            # top_k x capacity_factor)
+            # top_k)
             dp_size = n_chips // mesh.shape.get("model", 1)
             tokens_per_dev = shape.global_batch * shape.seq_len // max(dp_size, 1)
             target = 8192 if cfg.moe else 16384

@@ -64,11 +64,13 @@ def hint(x: Array, *logical: str | None) -> Array:
 # ---------------------------------------------------------------------------
 
 
-def winit(key, shape, fan_in=None, stacked=True, dtype=jnp.float32):
-    """Truncated-normal-ish init + its stacked per-layer clipping value."""
+def winit(key, shape, fan_in=None, stacked=True, dtype=jnp.float32,
+          lead=1):
+    """Truncated-normal-ish init + its stacked clipping value, one per
+    index of the ``lead`` leading axes (per layer; per layer and expert)."""
     fan_in = fan_in if fan_in is not None else shape[-2]
     w = jax.random.normal(key, shape, dtype) * np.sqrt(1.0 / fan_in)
-    return w, alpha_like(w, stacked=stacked and len(shape) > 2)
+    return w, alpha_like(w, stacked=stacked and len(shape) > 2, lead=lead)
 
 
 def put(params: dict, name: str, w_and_alpha):

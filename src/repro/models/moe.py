@@ -1,126 +1,137 @@
-"""Mixture-of-Experts FFN with capacity-based sort-free dispatch.
+"""Mixture-of-Experts FFN: an expert share with dropless routing.
 
-Token routing uses the rank-in-expert trick (argsort + first-occurrence
-searchsorted) to place each (token, choice) pair into a unique
-``(expert, slot)`` cell of a (E, C, D) dispatch buffer — no (N, E, C)
-one-hot tensors (which would be ~10^12 elements at train_4k scale). Expert
-FFNs then run as dense stacked einsums over the buffer, so compiled FLOPs
-scale with top_k * capacity_factor, not n_experts.
+A model holds ``cfg.moe.held`` of the ``n_experts`` routed experts, those
+from ``first_expert`` on, as one chip of an expert-parallel deployment
+does; with no share given it holds them all. Per layer:
 
-Distribution (§Perf cell 2): under a mesh, the whole dispatch+compute runs
-inside a ``shard_map`` that is *manual over the data axes and auto over
-model*: each data row routes only its own tokens into its own local
-capacity buffer (C_local = cf*K*N_local/E). Tokens never cross rows —
-the global-scatter formulation cost 1.2 TB/step of dispatch all-gathers
-on granite-moe train_4k (measured; see EXPERIMENTS.md). Statistically
-this is the standard "local dispatch" EP approximation: capacity is
-enforced per row rather than globally, and expert weights are shared
-(FSDP-gathered) as before.
+* **route** (``moe.route``): float32 router logits over all ``n_experts``
+  from the layer's (unquantized) input, the ``top_k`` largest, and a
+  softmax over those ``top_k`` (equal to the softmax over all experts
+  renormalised over the chosen ones);
+* **dispatch** (``moe.dispatch``): the ``N * top_k`` (token, choice)
+  assignments are sorted by local expert, those on experts not held last,
+  into one static ``(N * top_k, D)`` buffer whose rows
+  ``[offsets[e], offsets[e + 1])`` go to held expert ``e``. There is no
+  capacity: no assignment is dropped, whatever the skew;
+* **experts** (``moe.experts``): SwiGLU, ``down(silu(gate x) * up x)``, as
+  grouped matmuls over the buffer: on a Pallas backend one fused FP8-QAT
+  kernel per pass (``kernels.dispatch.qat_gmm``) that visits only the
+  held rows, so the work scales with the routed rows; elsewhere the
+  operands fake-quantized in float32 through ``kernels.dispatch.gmm``;
+* **combine** (``moe.combine``): each held row, scaled by its gate, is
+  added to its token.
 
-The router stays FP32 and un-quantized (DESIGN.md §6); expert weights
-carry per-(layer, expert) FP8 clipping values. Tokens overflowing an
-expert's per-row capacity are dropped (combine weight zero) — standard
-GShard behaviour, rare at capacity_factor 1.25 under balanced routing.
+Dispatch and combine move rows by one permutation of the ``N * top_k``
+assignments and its inverse (:func:`_permute_rows`): each direction, and
+each one's gradient, is a gather; a token's ``top_k`` rows are summed in
+float32.
+
+The result is the held experts' part of the layer's output: what the
+experts held elsewhere would add is not computed, and nothing stands in
+for it. The shares of all experts add up to the whole layer.
+
+QAT: the router is float32 and not quantized. Expert weights carry one
+clipping value per (layer, expert), LSQ-scaled by one expert's size; the
+expert input shares the layer's ``mlp_qb`` (LSQ over the ``N x D`` layer
+input, as the dense FFN) and the down projection's input ``down_qb``
+(LSQ over the ``N * top_k`` routed rows of ``d_ff``, the whole layer's).
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
 
 from ..configs.base import ModelConfig
-from ..core.qat import QATConfig, aq, wq
-from .common import _RULES, COMPUTE_DTYPE, activation, hint
+from ..core.qat import QATConfig, _lsq_grad_scale, aq
+from ..kernels import dispatch
+from .common import COMPUTE_DTYPE, activation
 
 Array = jax.Array
 
 
-def _expert_dense(p, name: str, x: Array, qcfg: QATConfig) -> Array:
-    """x: (E, C, d_in) @ stacked expert weights (E, d_in, d_out)."""
-    w = p[name]
+@jax.custom_vjp
+def _permute_rows(x: Array, perm: Array, inv: Array) -> Array:
+    """``x[perm]`` for a permutation ``perm`` whose inverse is ``inv``;
+    the gradient is the inverse gather, not a scatter-add."""
+    return x[perm]
+
+
+def _permute_rows_fwd(x, perm, inv):
+    return x[perm], inv
+
+
+def _permute_rows_bwd(inv, g):
+    return g[inv], None, None
+
+
+_permute_rows.defvjp(_permute_rows_fwd, _permute_rows_bwd)
+
+
+def _fused(qcfg: QATConfig) -> bool:
+    """Whether the experts take the fused grouped QAT matmul: full
+    deterministic QAT on a backend that runs the Pallas kernels."""
+    return (qcfg.enabled and qcfg.quantize_weights and qcfg.quantize_acts
+            and qcfg.mode == "det" and dispatch.use_kernels())
+
+
+def _experts(p, name: str, x: Array, site: str, n_act: int, offsets: Array,
+             qcfg: QATConfig) -> Array:
+    """Rows of ``x`` (sorted by held expert) through their expert's
+    ``p[name]`` (E_here, d_in, d_out), the input fake-quantized with
+    ``p[site]`` (LSQ over ``n_act`` elements) and each expert's weight with
+    its own clip: on the fused kernels, or as float32 quantizers and
+    :func:`dispatch.gmm`, the same arithmetic."""
+    w = p[name].astype(jnp.float32)
+    alpha = _lsq_grad_scale(p[name + "_qa"].reshape(-1).astype(jnp.float32),
+                            w[0].size, qcfg.fmt)
+    beta = p[site].astype(jnp.float32)
+    if _fused(qcfg):
+        return dispatch.qat_gmm(x, w, _lsq_grad_scale(beta, n_act, qcfg.fmt),
+                                alpha, offsets, qcfg.fmt)
+    x = aq(x.astype(jnp.float32), beta, qcfg, n=n_act)
     if qcfg.enabled and qcfg.quantize_weights:
-        w = wq(w.astype(jnp.float32), p[name + "_qa"], qcfg)
-    return jnp.einsum("ecd,edf->ecf", x, w.astype(COMPUTE_DTYPE))
-
-
-def _moe_tokens(p, xf: Array, cfg: ModelConfig, qcfg: QATConfig,
-                n_total_tokens: int, sharded_hints: bool = False) -> Array:
-    """Route + dispatch + expert-compute + combine for a flat token batch.
-
-    ``xf``: (N, D) — global batch outside a mesh, or the row-local shard
-    inside the shard_map. Capacity derives from ``n_total_tokens`` == N.
-    """
-    N, D = xf.shape
-    m = cfg.moe
-    E, K = m.n_experts, m.top_k
-    xf = aq(xf, p["mlp_qb"].astype(jnp.float32), qcfg)
-
-    # ---- routing (FP32) ----------------------------------------------------
-    logits = jnp.einsum(
-        "nd,de->ne", xf.astype(jnp.float32), p["router"].astype(jnp.float32)
-    )
-    gates_all = jax.nn.softmax(logits, axis=-1)
-    gate_vals, expert_idx = jax.lax.top_k(gates_all, K)      # (N, K)
-    gate_vals = gate_vals / jnp.sum(gate_vals, axis=-1, keepdims=True)
-
-    # ---- rank of each (token, choice) within its expert ---------------------
-    flat_e = expert_idx.reshape(-1)                          # (N*K,)
-    order = jnp.argsort(flat_e, stable=True)
-    sorted_e = flat_e[order]
-    first = jnp.searchsorted(sorted_e, sorted_e, side="left")
-    rank_sorted = jnp.arange(N * K) - first
-    rank = jnp.zeros_like(rank_sorted).at[order].set(rank_sorted)
-
-    C = max(int(m.capacity_factor * n_total_tokens * K / E), 1)
-    keep = rank < C
-    slot = jnp.where(keep, flat_e * C + rank, E * C)         # overflow -> waste row
-
-    # ---- dispatch ------------------------------------------------------------
-    xr = jnp.repeat(xf.astype(COMPUTE_DTYPE), K, axis=0)     # (N*K, D)
-    if sharded_hints:
-        xr = hint(xr, None, "tp")
-    buf = jnp.zeros((E * C + 1, D), COMPUTE_DTYPE).at[slot].set(xr)
-    if sharded_hints:
-        buf = hint(buf, None, "tp")
-    buf = buf[: E * C].reshape(E, C, D)
-    if sharded_hints:
-        # capacity rows over data (EP), model dim over TP — without these
-        # the scatter output replicates per device (55-96 GB measured)
-        buf = hint(buf, None, "batch", "tp")
-
-    # ---- expert compute (stacked, QAT-quantized) ------------------------------
-    g = _expert_dense(p, "we_gate", buf, qcfg)
-    u = _expert_dense(p, "we_up", buf, qcfg)
-    hmid = activation(g, cfg.act) * u
-    if sharded_hints:
-        hmid = hint(hmid, None, "batch", "tp")
-    hmid = aq(hmid, p["down_qb"].astype(jnp.float32), qcfg)
-    out_buf = _expert_dense(p, "we_down", hmid, qcfg)        # (E, C, D)
-    if sharded_hints:
-        out_buf = hint(out_buf, None, "batch", "tp")
-
-    # ---- combine ---------------------------------------------------------------
-    out_flat = out_buf.reshape(E * C, D)
-    out_flat = jnp.concatenate(
-        [out_flat, jnp.zeros((1, D), COMPUTE_DTYPE)], axis=0
-    )
-    gathered = out_flat[slot].reshape(N, K, D)
-    w = (gate_vals * keep.reshape(N, K)).astype(jnp.float32)
-    y = jnp.einsum("nkd,nk->nd", gathered.astype(jnp.float32), w)
-    return y.astype(xf.dtype)
+        w = dispatch.quantize_det(w, alpha.reshape(-1, 1, 1), qcfg.fmt)
+    return dispatch.gmm(x, w, offsets).astype(COMPUTE_DTYPE)
 
 
 def moe_ffn(p, x: Array, cfg: ModelConfig, qcfg: QATConfig) -> Array:
-    """x: (B, T, D) -> (B, T, D). ``p`` holds this layer's slice.
-
-    NOTE (§Perf cell 2): a row-local EP variant (shard_map manual over the
-    data axes, auto over model) removes the cross-row dispatch collectives
-    entirely, but partial-auto shard_map nested inside scan+vjp aborts the
-    XLA:CPU SPMD partitioner (C++ crash) at jax 0.8 — the global-dispatch
-    formulation below with explicit buffer sharding hints is the shipped
-    path; the EP variant is the recorded next step for real-TPU infra.
-    """
+    """x: (B, T, D) -> (B, T, D), the held experts' part. ``p`` holds this
+    layer's slice."""
     B, T, D = x.shape
-    y = _moe_tokens(p, x.reshape(B * T, D), cfg, qcfg, B * T,
-                    sharded_hints=True)
-    return y.reshape(B, T, D).astype(x.dtype)
+    m = cfg.moe
+    N, K, G = B * T, m.top_k, m.held
+    xf = x.reshape(N, D)
+
+    with jax.named_scope("moe.route"):
+        logits = jnp.einsum("nd,de->ne", xf.astype(jnp.float32),
+                            p["router"].astype(jnp.float32))
+        top, expert = jax.lax.top_k(logits, K)                   # (N, K)
+        gates = jax.nn.softmax(top, axis=-1)
+
+    with jax.named_scope("moe.dispatch"):
+        local = expert.reshape(-1) - m.first_expert              # (N*K,)
+        held = (local >= 0) & (local < G)
+        slot = jnp.where(held, local, G)
+        order = jnp.argsort(slot, stable=True)
+        inverse = jnp.zeros_like(order).at[order].set(
+            jnp.arange(N * K, dtype=order.dtype))
+        sizes = jnp.bincount(slot, length=G + 1)[:G]
+        offsets = jnp.concatenate(
+            [jnp.zeros((1,), jnp.int32), jnp.cumsum(sizes).astype(jnp.int32)])
+        weight = jnp.where(held, gates.reshape(-1), 0.0)[order]
+        # a token's rows repeated in f32, so their gradients sum in f32
+        xs = _permute_rows(jnp.repeat(xf.astype(jnp.float32), K, axis=0),
+                           order, inverse).astype(COMPUTE_DTYPE)
+
+    with jax.named_scope("moe.experts"):
+        g = _experts(p, "we_gate", xs, "mlp_qb", N * D, offsets, qcfg)
+        u = _experts(p, "we_up", xs, "mlp_qb", N * D, offsets, qcfg)
+        h = activation(g, cfg.act) * u
+        y = _experts(p, "we_down", h, "down_qb", N * K * cfg.d_ff, offsets,
+                     qcfg)
+
+    with jax.named_scope("moe.combine"):
+        rows = _permute_rows(y.astype(jnp.float32) * weight[:, None],
+                             inverse, order)
+        out = jnp.sum(rows.reshape(N, K, D), axis=1)
+    return out.astype(x.dtype).reshape(B, T, D)

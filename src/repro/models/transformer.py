@@ -77,11 +77,13 @@ def _init_ffn(key, cfg: ModelConfig, L: int) -> dict:
     p: dict = {}
     ks = jax.random.split(key, 4)
     if cfg.moe:
-        E = cfg.moe.n_experts
+        # the router scores every expert; the weights are the held share's,
+        # each with its own clip (L, E_here, 1, 1)
+        E, Eh = cfg.moe.n_experts, cfg.moe.held
         p["router"] = jax.random.normal(ks[0], (L, D, E), jnp.float32) * 0.02
-        put(p, "we_gate", winit(ks[1], (L, E, D, F), fan_in=D))
-        put(p, "we_up", winit(ks[2], (L, E, D, F), fan_in=D))
-        put(p, "we_down", winit(ks[3], (L, E, F, D), fan_in=F))
+        put(p, "we_gate", winit(ks[1], (L, Eh, D, F), fan_in=D, lead=2))
+        put(p, "we_up", winit(ks[2], (L, Eh, D, F), fan_in=D, lead=2))
+        put(p, "we_down", winit(ks[3], (L, Eh, F, D), fan_in=F, lead=2))
     else:
         put(p, "w_gate", winit(ks[0], (L, D, F)))
         put(p, "w_up", winit(ks[1], (L, D, F)))

@@ -6,9 +6,12 @@ and must lower to a ``tpu_custom_call``. This catches what interpret mode
 cannot: casts, scalar stores and reshapes Mosaic refuses. Widths: a
 4096 x 1024 wire/plane tile layout, and TinyLlama's MLP matmul
 (2048 x 2048 @ 2048 x 5632), and the QAT matmul with bf16 activations at
-Granite-3-8B's widths. A small federated round compiles the same
-way: its kernels keep the names the benchmark's readers match
-(``bench/kernels.py``), and each runs under a stage scope of the round.
+Granite-3-8B's widths, and the grouped QAT matmul at Granite-3.0-3B-A800M's
+expert widths (20 experts held, 4096 tokens x 8 routed rows). A small
+federated round compiles the same way: its kernels keep the names the
+benchmark's readers match (``bench/kernels.py``), and each runs under a
+stage scope of the round; so does a small round of the sparse-expert
+cell, with nothing routed to jnp.
 
 The topology is described inside a module fixture, never at import: only
 one process at a time may hold the TPU library, and every test worker
@@ -32,7 +35,8 @@ M, K, N = 2048, 2048, 5632
 # The kernel families the benchmark's readers find by substring:
 # ``wire.kernel_ms`` (encode, decode) and the two ``*_roofline`` metrics.
 FAMILIES = ("qat_matmul", "qat_matmul_dx", "qat_matmul_dw",
-            "quant_pack_tiles", "unpack_tiles")
+            "quant_pack_tiles", "unpack_tiles",
+            "qat_gmm", "qat_gmm_dx", "qat_gmm_dw")
 KERNEL_CALL = re.compile(r"^\s*(?:ROOT\s+)?%([\w.\-]+) = .*"
                          r'custom_call_target="tpu_custom_call"')
 
@@ -142,6 +146,7 @@ def _cases(S):
                 g, a, b, be, al, **kw),
             (mat, x, w, scalar, scalar)),
         **_bf16_matmul_cases(S, kw),
+        **_gmm_cases(S, kw),
     }
 
 
@@ -169,6 +174,34 @@ def _bf16_matmul_cases(S, kw):
             lambda g, a, b, be, al: fp8_matmul.qat_matmul_dw(
                 g, a, b, be, al, **kw),
             (g, x, w, scalar, scalar))
+    return cases
+
+
+# Granite-3.0-3B-A800M's experts: 20 held, (1536 -> 512) gate and up,
+# (512 -> 1536) down, over the 4096 x 8 routed rows of a 4096-token step.
+GMM_WIDTHS, GMM_GROUPS, GMM_ROWS = ((1536, 512), (512, 1536)), 20, 32768
+
+
+def _gmm_cases(S, kw):
+    bf, f32, i32 = jnp.bfloat16, jnp.float32, jnp.int32
+    beta, alpha = S((), f32), S((GMM_GROUPS,), f32)
+    offsets = S((GMM_GROUPS + 1,), i32)
+    cases = {}
+    for k, n in GMM_WIDTHS:
+        x, g = S((GMM_ROWS, k), bf), S((GMM_ROWS, n), bf)
+        w = S((GMM_GROUPS, k, n), f32)
+        tag = f"bf16_{k}x{n}"
+        cases[f"qat_gmm_{tag}"] = (
+            lambda a, b, be, al, o: fp8_matmul.qat_gmm(a, b, be, al, o, **kw),
+            (x, w, beta, alpha, offsets))
+        cases[f"qat_gmm_dx_{tag}"] = (
+            lambda g, a, b, be, al, o: fp8_matmul.qat_gmm_dx(
+                g, a, b, be, al, o, **kw),
+            (g, x, w, beta, alpha, offsets))
+        cases[f"qat_gmm_dw_{tag}"] = (
+            lambda g, a, b, be, al, o: fp8_matmul.qat_gmm_dw(
+                g, a, b, be, al, o, **kw),
+            (g, x, w, beta, alpha, offsets))
     return cases
 
 
@@ -267,3 +300,50 @@ def test_round_kernels_run_under_a_stage(round_kernels):
                          "qat_matmul_dw": {"fl.train"},
                          "quant_pack_tiles": {"fl.down", "fl.up"},
                          "unpack_tiles": {"fl.down", "fl.up"}}
+
+
+@pytest.fixture(scope="module")
+def moe_round(one_chip, no_persistent_cache):
+    """A small round of the sparse-expert cell (its build, its expert
+    share of half the router's experts) compiled for one v5e chip: the
+    HLO text, the ops routed to jnp, and the QAT kernels traced."""
+    import sys
+    from pathlib import Path
+
+    root = str(Path(__file__).resolve().parents[1])
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from bench import harness
+
+    cell = harness.load_cell(
+        "granite-moe-3b-a800m.silo", "granite-moe-3b-a800m",
+        cfg=dict(hidden_size=128, intermediate_size=128,
+                 num_attention_heads=2, num_key_value_heads=1, head_dim=64,
+                 vocab_size=256, num_hidden_layers=1, router_experts=8,
+                 num_experts_per_tok=2, num_local_experts=4),
+        traffic=dict(rows_per_client=4, seq=64, local_steps=1, batch=2))
+    inp = harness.make_inputs(cell, 7)
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("REPRO_KERNEL_BACKEND", "pallas")
+        routes = collections.Counter(dispatch.JNP_ROUTES)
+        traced = collections.Counter(dispatch.QAT_MXU)
+        eng, state = cell.sut.build(cell.cfg, cell.traffic, inp.params(),
+                                    inp.data, inp.labels, inp.nk)
+        args = (state, inp.data, inp.labels, inp.nk, inp.keys[0])
+        shapes = jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            np.shape(x), jnp.result_type(x), sharding=one_chip), args)
+        text = jax.jit(eng.round_fn).lower(*shapes).compile().as_text()
+    return (text, dispatch.JNP_ROUTES - routes, dispatch.QAT_MXU - traced)
+
+
+def test_moe_round_runs_every_expert_matmul_in_the_kernels(moe_round):
+    text, routes, traced = moe_round
+    assert not routes, routes
+    assert {k for k in traced if k.startswith("qat_gmm")} == {
+        "qat_gmm:bfloat16", "qat_gmm_dx:bfloat16", "qat_gmm_dw:bfloat16"}
+    gmm = [(n, on) for n, on in _kernel_calls(text) if "qat_gmm" in n]
+    assert {_family(n) for n, _ in gmm} == {
+        "qat_gmm", "qat_gmm_dx", "qat_gmm_dw"}
+    for n, on in gmm:
+        parts = on.split("/")
+        assert "fl.train" in parts and "moe.experts" in parts, (n, on)
